@@ -22,6 +22,9 @@ def main() -> int:
     args = ap.parse_args()
     fast = args.fast or args.smoke
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (bench_kernels, bench_reuse, bench_roofline,
                             bench_space, bench_steps, bench_throughput)
     benches = {

@@ -29,7 +29,7 @@ from repro.core import batched as BT
 from repro.core import encoding as E
 from repro.core import hashing as H
 from repro.core.spec import OP_LOOKUP
-from repro.dist.compat import axis_size, shard_map
+from repro.dist.compat import shard_map
 
 SHARD_SEED = 0x5EED
 
@@ -82,7 +82,7 @@ def routed_apply(st_local: ShardedTable, ops, keys, *, axis_name: str,
     ops = jnp.asarray(ops, jnp.int32)
     keys = jnp.asarray(keys, jnp.uint32)
     B = keys.shape[0]
-    S = axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
 
     dest = shard_of(keys, S)                              # [B]
     # position of each request within its destination bucket

@@ -12,7 +12,7 @@ production deployment needs when chips stall or drop:
 - ``compression``     — int8 gradient compression with error feedback
 - ``fault_tolerance`` — watchdog, straggler monitor, elastic remeshing
 - ``pipeline``        — GPipe-style pipeline parallelism over the pod axis
-- ``compat``          — shard_map/axis_size shims across jax versions
+- ``compat``          — the shard_map facade (binds ``ctx.manual_axes``)
 
 Submodules are imported lazily so that ``from repro.dist import ctx`` never
 drags the model stack (``tp`` imports ``models.layers``) into lightweight

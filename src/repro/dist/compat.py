@@ -1,37 +1,24 @@
-"""Version shims for jax APIs the codebase uses.
+"""``shard_map`` facade.
 
-The repo targets the modern spellings (``jax.shard_map`` with
-``axis_names=``/``check_vma=``, ``jax.lax.axis_size``); older jaxlibs (the
-pinned CPU test toolchain is 0.4.x) expose the same machinery under
-``jax.experimental.shard_map.shard_map(..., auto=..., check_rep=...)`` and
-``psum(1, axis)``.  Everything routes through here so call sites stay on one
-spelling.
+Every region in the repo goes through here so that its body runs inside
+``ctx.manual_axes``: ``shard_act`` then knows which mesh axes it must not
+constrain over.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 
 from repro.dist import ctx
 
-try:  # jax >= 0.6: public API with axis_names/check_vma
-    _MODERN = hasattr(jax, "shard_map")
-except Exception:  # pragma: no cover
-    _MODERN = False
-
-if not _MODERN:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
 
 def shard_map(f=None, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = True):
-    """``jax.shard_map`` facade.
+    """``jax.shard_map`` with the body run under ``ctx.manual_axes``.
 
     ``axis_names``: the *manual* mesh axes (default: all of them); the rest
-    stay auto (GSPMD).  The wrapped body runs inside ``ctx.manual_axes`` so
-    ``shard_act`` knows which axes it must not constrain over.
+    stay auto (GSPMD).
     Usable directly or via ``functools.partial(shard_map, mesh=..., ...)``.
     """
     if f is None:
@@ -46,19 +33,6 @@ def shard_map(f=None, *, mesh, in_specs, out_specs, axis_names=None,
         with ctx.manual_axes(manual):
             return f(*args)
 
-    if _MODERN:
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(manual),
-                             check_vma=check_vma)
-    auto = frozenset(mesh.axis_names) - manual
-    return _legacy_shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma,
-                             auto=auto)
-
-
-def axis_size(name) -> int:
-    """``jax.lax.axis_size`` fallback: the static size of a bound mapped
-    axis (psum of 1 — folded to a constant at trace time)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(manual),
+                         check_vma=check_vma)

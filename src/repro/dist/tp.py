@@ -130,8 +130,8 @@ def _mlp_manual(rules, mp, ln, x):
     x_spec = P(dp, None, None) if dp else P()
 
     def fn(mp_l, ln_l, x):
-        y = L.mlp_apply(mp_l, nn.rmsnorm(ln_l, x))
-        return jax.lax.psum(y, "model")
+        y = L.mlp_partial(mp_l, nn.rmsnorm(ln_l, x))
+        return jax.lax.psum(y, "model").astype(x.dtype)
 
     mapped = shard_map(
         fn, mesh=mesh,
@@ -310,7 +310,7 @@ def decode_param_specs(cfg, params, *, vocab_sharded: bool,
 def mlp_decode_manual(mp, x):
     """SwiGLU MLP on a d_ff column shard + row-parallel wo; runs INSIDE an
     enclosing manual region that owns the model axis.  x [B, S, d]."""
-    return jax.lax.psum(L.mlp_apply(mp, x), "model")
+    return jax.lax.psum(L.mlp_partial(mp, x), "model").astype(x.dtype)
 
 
 def logits_decode_manual(cfg, params, x, *, vocab_sharded: bool):

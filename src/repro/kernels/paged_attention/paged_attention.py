@@ -6,10 +6,13 @@ the physical page index, so the pool is addressed through scalar-prefetched
 ``page_ids`` feeding the K/V BlockSpec index_maps — one DMA per (seq,
 kv-head, page) grid step, online-softmax accumulation in VMEM scratch.
 
-Grid: (B, KH, MP), MP innermost (sequential on TPU; scratch persists across
-the page loop).  Block shapes: q [1,1,G,D], K/V [1,PS,1,D] selected by
-page_ids[b,p] — D should be a multiple of 128 and PS a multiple of 8 on real
-hardware; interpret-mode tests use small shapes.
+Grid: (B, MP), MP innermost (sequential on TPU; scratch persists across
+the page loop).  Block shapes: q [1,KH,G,D], K/V [1,PS,KH,D] selected by
+page_ids[b,p] — one DMA brings a whole page (every kv head), and the kernel
+walks the heads in a static loop.  A one-head block [1,PS,1,D] would break
+Mosaic's rule that a block's last two dims divide by (8, 128) or equal the
+array's; the whole-page block equals them.  Each head's f32 op sequence is
+the same as a per-head grid step, so results do not change.
 
 Pages past ``lens[b]`` or with id -1 are masked (index_map clamps to page 0;
 the mask keeps the math exact).
@@ -27,17 +30,18 @@ NEG_INF = -1e30
 
 
 def _pa_kernel(page_ids_ref, lens_ref,      # scalar prefetch [B,MP], [B]
-               q_ref,                        # [1, 1, G, D]
-               k_ref,                        # [1, PS, 1, D]
-               v_ref,                        # [1, PS, 1, D]
+               q_ref,                        # [1, KH, G, D]
+               k_ref,                        # [1, PS, KH, D]
+               v_ref,                        # [1, PS, KH, D]
                *rest,                        # [ks_ref, vs_ref,] o_ref, scratch
-               PS: int, G: int, D: int, MP: int, quantized: bool = False):
-    if quantized:                            # int8 pools: [1, PS, 1] bf16
+               PS: int, KH: int, G: int, D: int, MP: int,
+               quantized: bool = False):
+    if quantized:                            # int8 pools: [1, PS, KH] bf16
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
@@ -53,34 +57,36 @@ def _pa_kernel(page_ids_ref, lens_ref,      # scalar prefetch [B,MP], [B]
 
     @pl.when(jnp.any(valid))
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)            # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)         # [PS, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)         # [PS, D]
-        if quantized:                                  # dequant in f32
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * (D ** -0.5)                            # [G, PS]
-        s = jnp.where(valid[None, :], s, NEG_INF)
-        m_prev = m_scr[...][:, 0]                      # [G]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)                # [G]
-        pexp = jnp.exp(s - m_new[:, None])             # [G, PS]
-        pexp = jnp.where(valid[None, :], pexp, 0.0)
-        l_new = l_scr[...][:, 0] * alpha + jnp.sum(pexp, axis=1)
-        acc = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new[:, None]
-        l_scr[...] = l_new[:, None]
-        acc_scr[...] = acc
+        for h in range(KH):                  # static: one page, every head
+            q = q_ref[0, h].astype(jnp.float32)            # [G, D]
+            k = k_ref[0, :, h].astype(jnp.float32)         # [PS, D]
+            v = v_ref[0, :, h].astype(jnp.float32)         # [PS, D]
+            if quantized:                                  # dequant in f32
+                k = k * ks_ref[0, :, h].astype(jnp.float32)[:, None]
+                v = v * vs_ref[0, :, h].astype(jnp.float32)[:, None]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * (D ** -0.5)                            # [G, PS]
+            s = jnp.where(valid[None, :], s, NEG_INF)
+            m_prev = m_scr[h][:, 0]                        # [G]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            alpha = jnp.exp(m_prev - m_new)                # [G]
+            pexp = jnp.exp(s - m_new[:, None])             # [G, PS]
+            pexp = jnp.where(valid[None, :], pexp, 0.0)
+            l_new = l_scr[h][:, 0] * alpha + jnp.sum(pexp, axis=1)
+            acc = acc_scr[h] * alpha[:, None] + jax.lax.dot_general(
+                pexp, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new[:, None]
+            l_scr[h] = l_new[:, None]
+            acc_scr[h] = acc
 
     @pl.when(p == MP - 1)
     def _finish():
-        l = l_scr[...][:, 0]
-        norm = jnp.where(l > 0, 1.0 / jnp.maximum(l, 1e-30), 0.0)
-        o_ref[0, 0] = (acc_scr[...] * norm[:, None]).astype(o_ref.dtype)
+        for h in range(KH):
+            l = l_scr[h][:, 0]
+            norm = jnp.where(l > 0, 1.0 / jnp.maximum(l, 1e-30), 0.0)
+            o_ref[0, h] = (acc_scr[h] * norm[:, None]).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q, k_pages, v_pages, page_ids, lens, *,
@@ -96,36 +102,36 @@ def paged_attention_kernel(q, k_pages, v_pages, page_ids, lens, *,
     q4 = q.reshape(B, KH, G, D)
     quantized = scales is not None
 
-    def _kv_map(b, h, p, ids, ln):
+    def _kv_map(b, p, ids, ln):
         # clamp only for addressing; the kernel masks on the raw -1 sentinel
-        return (jnp.clip(ids[b, p], 0, NP - 1), 0, h, 0)
+        return (jnp.clip(ids[b, p], 0, NP - 1), 0, 0, 0)
 
-    def _sc_map(b, h, p, ids, ln):
-        return (jnp.clip(ids[b, p], 0, NP - 1), 0, h)
+    def _sc_map(b, p, ids, ln):
+        return (jnp.clip(ids[b, p], 0, NP - 1), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, G, D), lambda b, h, p, ids, ln: (b, h, 0, 0)),
-        pl.BlockSpec((1, PS, 1, D), _kv_map),
-        pl.BlockSpec((1, PS, 1, D), _kv_map),
+        pl.BlockSpec((1, KH, G, D), lambda b, p, ids, ln: (b, 0, 0, 0)),
+        pl.BlockSpec((1, PS, KH, D), _kv_map),
+        pl.BlockSpec((1, PS, KH, D), _kv_map),
     ]
     operands = [q4, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, PS, 1), _sc_map)] * 2
+        in_specs += [pl.BlockSpec((1, PS, KH), _sc_map)] * 2
         operands += [scales[0], scales[1]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KH, MP),
+        grid=(B, MP),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, p, ids, ln: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KH, G, D),
+                               lambda b, p, ids, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((KH, G, 1), jnp.float32),
+            pltpu.VMEM((KH, G, 1), jnp.float32),
+            pltpu.VMEM((KH, G, D), jnp.float32),
         ],
     )
-    kernel = functools.partial(_pa_kernel, PS=PS, G=G, D=D, MP=MP,
+    kernel = functools.partial(_pa_kernel, PS=PS, KH=KH, G=G, D=D, MP=MP,
                                quantized=quantized)
     out = pl.pallas_call(
         kernel,

@@ -51,12 +51,12 @@ BIG = 1 << 30  # python int: inlined as an immediate, not a captured const
 
 
 def _probe_kernel(bstart_ref,            # scalar prefetch: int32[nt]
-                  keys_ref,              # uint32[1, KT]
-                  hv_ref,                # int32[1, KT]
+                  keys_ref,              # uint32[1, KT] SMEM
+                  hv_ref,                # int32[1, KT] SMEM
                   tab_hbm,               # uint32[nb*TB//128, 128] HBM (ANY)
-                  found_ref,             # int32[1, KT]
-                  slot_ref,              # int32[1, KT]
-                  resolved_ref,          # int32[1, KT]
+                  found_ref,             # int32[1, KT] SMEM
+                  slot_ref,              # int32[1, KT] SMEM
+                  resolved_ref,          # int32[1, KT] SMEM
                   win_ref,               # uint32[2, 2*TB//128, 128] VMEM
                   sem,                   # DMA sem (2 slots, 2 blocks)
                   *, TB: int, KT: int, m: int):
@@ -145,22 +145,22 @@ def probe_lookup_kernel(table, keys_sorted, hv_sorted, bstart, *,
     assert keys_sorted.shape[0] == nt * KT
 
     table2d = table.reshape(nb * (TB // LANES), LANES)
-    keys2d = keys_sorted.reshape(nt, KT)
-    hv2d = hv_sorted.reshape(nt, KT)
+    # per-key operands live in SMEM (the probe loop reads and writes one
+    # scalar per key); the unit middle dim makes each (1, KT) tile equal
+    # the array's last two dims, as Mosaic requires of block shapes
+    keys3d = keys_sorted.reshape(nt, 1, KT)
+    hv3d = hv_sorted.reshape(nt, 1, KT)
+
+    def tile():
+        return pl.BlockSpec((None, 1, KT), lambda t, s: (t, 0, 0),
+                            memory_space=pltpu.SMEM)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nt,),
-        in_specs=[
-            pl.BlockSpec((1, KT), lambda t, s: (t, 0)),
-            pl.BlockSpec((1, KT), lambda t, s: (t, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # whole table in HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((1, KT), lambda t, s: (t, 0)),
-            pl.BlockSpec((1, KT), lambda t, s: (t, 0)),
-            pl.BlockSpec((1, KT), lambda t, s: (t, 0)),
-        ],
+        in_specs=[tile(), tile(),
+                  pl.BlockSpec(memory_space=pl.ANY)],  # table in HBM
+        out_specs=[tile(), tile(), tile()],
         scratch_shapes=[
             pltpu.VMEM((2, 2 * (TB // LANES), LANES), jnp.uint32),
             pltpu.SemaphoreType.DMA((2, 2)),
@@ -171,10 +171,10 @@ def probe_lookup_kernel(table, keys_sorted, hv_sorted, bstart, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((nt, KT), jnp.int32),
-            jax.ShapeDtypeStruct((nt, KT), jnp.int32),
-            jax.ShapeDtypeStruct((nt, KT), jnp.int32),
+            jax.ShapeDtypeStruct((nt, 1, KT), jnp.int32),
+            jax.ShapeDtypeStruct((nt, 1, KT), jnp.int32),
+            jax.ShapeDtypeStruct((nt, 1, KT), jnp.int32),
         ],
         interpret=interpret,
-    )(bstart, keys2d, hv2d, table2d)
+    )(bstart, keys3d, hv3d, table2d)
     return found.reshape(-1), slot.reshape(-1), resolved.reshape(-1)

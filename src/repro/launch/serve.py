@@ -27,6 +27,11 @@ With ``Scheduler(proactive=False)`` the driver degenerates to the old
 reactive batcher (admit greedily, rebuild after the abort) — the baseline
 the adversarial churn tests compare against.
 
+The entry point builds its mesh from the devices it sees (one data row,
+the model axis as wide as the device count) and draws the parameters on
+the device from ``--seed``; ``run`` is the same serving loop for callers
+that bring their own config and parameters (``chip_smoke.py``).
+
 Usage (CPU smoke):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-32b --smoke \
       --rounds 6 --batch 4 --max-len 48 --megastep 4 --policy deadline \
@@ -38,6 +43,7 @@ import argparse
 import dataclasses
 import logging
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -45,8 +51,11 @@ import numpy as np
 
 from repro import obs as OBS
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
+from repro.dist.sharding import serve_manual_rules, serve_rules
 from repro.kernels import stats as KS
-from repro.models.registry import get_model
+from repro.launch import compile_cache as CC
+from repro.launch.mesh import make_serve_mesh
+from repro.models.registry import init_params
 from repro.serving import engine as EG
 from repro.serving import page_table as PT
 from repro.serving.sched import (Scheduler, churn_request,
@@ -83,8 +92,11 @@ class ContinuousBatcher:
                                              page_size=page_size,
                                              n_pages=n_pages)
         self.state["active"] = jnp.zeros((batch,), bool)  # no lanes seated
+        # the state is donated: each megastep updates the KV pools in place
+        # instead of holding a second copy of them
         self.mega_fn = jax.jit(EG.make_serve_megastep(
-            cfg, S_max=max_len, K=self.K, rules=rules, page_size=page_size))
+            cfg, S_max=max_len, K=self.K, rules=rules, page_size=page_size),
+            donate_argnums=(1,))
         pool = EG.decode_headroom(self.state, strategy=self.strategy)
         self.sched = scheduler or Scheduler(
             slots=batch, page_size=page_size, max_len=max_len,
@@ -405,7 +417,13 @@ class ContinuousBatcher:
         self._emit("summary", **self.sched.summary())
 
 
-def main():
+def _span(text: str):
+    """An inclusive ``LO,HI`` range flag."""
+    lo, hi = (int(v) for v in text.split(","))
+    return lo, hi
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-32b", choices=sorted(ARCH_IDS))
     ap.add_argument("--smoke", action="store_true")
@@ -422,6 +440,12 @@ def main():
                     choices=["fcfs", "priority", "deadline"])
     ap.add_argument("--requests", type=int, default=0,
                     help="fixed synthetic workload size (0 = endless churn)")
+    ap.add_argument("--prompt-len", type=_span, default=(2, 6),
+                    metavar="LO,HI",
+                    help="workload prompt lengths, inclusive range")
+    ap.add_argument("--max-new", type=_span, default=(8, 24),
+                    metavar="LO,HI",
+                    help="workload new-token budgets, inclusive range")
     ap.add_argument("--arrival-every", type=int, default=0,
                     help="stagger arrivals by N steps (0 = storm)")
     ap.add_argument("--slo-fraction", type=float, default=0.5,
@@ -453,19 +477,36 @@ def main():
     ap.add_argument("--metrics-out", default=None, metavar="PREFIX",
                     help="write PREFIX.prom (Prometheus text) and "
                          "PREFIX.json registry snapshots at exit")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    if args.probe_strategy != cfg.probe_strategy:
-        cfg = dataclasses.replace(cfg, probe_strategy=args.probe_strategy)
-    if args.telemetry:
-        cfg = dataclasses.replace(cfg, telemetry=True)
-    model = get_model(cfg)
-    params, _ = model.init(cfg, jax.random.PRNGKey(0))
 
-    maxP = -(-args.max_len // args.page_size)
-    default_pool = int(args.batch * maxP * 1.25) + 1
-    n_pages = max(maxP, int(default_pool * args.overcommit))
+def serve_rules_for(cfg, mesh):
+    """The decode sharding rules ``cfg.tp_impl`` asks for on ``mesh``."""
+    return (serve_manual_rules(mesh) if cfg.tp_impl == "manual"
+            else serve_rules(mesh))
+
+
+def peak_bytes_in_use() -> int | None:
+    """Largest ``peak_bytes_in_use`` over the local devices (None where the
+    backend does not report it)."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.local_devices()]
+    peaks = [p for p in peaks if p is not None]
+    return max(peaks) if peaks else None
+
+
+def run(cfg, params, args: argparse.Namespace, *, rules=None) -> dict:
+    """Serve the traffic ``args`` describe with ``params`` and return the
+    summary: the scheduler's roll-up plus ``requests`` (completed),
+    ``tokens`` (sampled), ``prompt_tokens``, ``wall_s`` (serving loop,
+    compiles included), the compile counts of
+    ``compile_cache.count_compiles``, ``peak_bytes_in_use``, ``drained`` and
+    ``rc`` (0, or 1 when a fixed workload did not drain or an ABORT
+    surfaced under ``--fail-on-abort``)."""
+    n_chips = 1 if rules is None else rules.mesh.size
+    maxP, plan = EG.plan_pages(cfg, args.batch, args.max_len,
+                               args.page_size, n_chips)
+    n_pages = max(maxP, int(plan * args.overcommit))
     sched = Scheduler(slots=args.batch, page_size=args.page_size,
                       max_len=args.max_len, megastep_k=args.megastep,
                       policy=args.policy,
@@ -474,32 +515,37 @@ def main():
     tracer = OBS.Tracer(args.trace) if args.trace else None
     srv = ContinuousBatcher(cfg, params, batch=args.batch,
                             max_len=args.max_len, page_size=args.page_size,
-                            megastep_k=args.megastep,
+                            rules=rules, megastep_k=args.megastep,
                             verify_block_table=args.verify_block_table,
                             scheduler=sched, n_pages=n_pages,
                             auto_refill=not fixed, seed=args.seed,
                             tracer=tracer)
-    print(f"[serve] fallback report: {EG.fallback_report(cfg, None)}")
+    print(f"[serve] fallback report: {EG.fallback_report(cfg, rules)}")
     if fixed:
         sched.submit_many(synthetic_workload(
             args.requests, vocab_size=cfg.vocab_size, max_len=args.max_len,
-            seed=args.seed, slo_fraction=args.slo_fraction,
+            seed=args.seed, prompt_len=args.prompt_len,
+            max_new=args.max_new, slo_fraction=args.slo_fraction,
             arrival_every=args.arrival_every))
 
-    for r in range(args.rounds):
-        srv.decode_round(args.steps_per_round)
-        st = srv.table_stats()
-        s = sched.stats
-        occ = ("" if st is None else
-               f" live_pages={int(st.live_pages)} "
-               f"tombs={int(st.tombstones)} "
-               f"occupancy={float(st.occupancy):.3f}")
-        print(f"[serve] round {r}: done={s.completed} "
-              f"preempted={s.preemptive_evictions} queue={len(sched.queue)} "
-              f"aborts={s.aborts} avoided={s.aborts_avoided} "
-              f"grows={s.pool_grows}{occ}")
-        if fixed and sched.drained:
-            break
+    t0 = time.perf_counter()
+    with CC.count_compiles() as compiles:
+        for r in range(args.rounds):
+            srv.decode_round(args.steps_per_round)
+            st = srv.table_stats()
+            s = sched.stats
+            occ = ("" if st is None else
+                   f" live_pages={int(st.live_pages)} "
+                   f"tombs={int(st.tombstones)} "
+                   f"occupancy={float(st.occupancy):.3f}")
+            print(f"[serve] round {r}: done={s.completed} "
+                  f"preempted={s.preemptive_evictions} "
+                  f"queue={len(sched.queue)} aborts={s.aborts} "
+                  f"avoided={s.aborts_avoided} grows={s.pool_grows}{occ}")
+            if fixed and sched.drained:
+                break
+        jax.block_until_ready(srv.state)
+    wall = time.perf_counter() - t0
 
     summary = sched.summary()
     print(f"[serve] summary ({sched.policy.name}, "
@@ -521,14 +567,33 @@ def main():
         with open(args.metrics_out + ".json", "w") as f:
             f.write(srv.metrics_json())
         print(f"[serve] metrics: {args.metrics_out}.prom / .json")
+    summary.update(
+        requests=len(sched.finished),
+        tokens=sum(len(r.sampled) for r in sched.finished),
+        prompt_tokens=sum(int(r.prompt.size) for r in sched.finished),
+        wall_s=wall, peak_bytes_in_use=peak_bytes_in_use(),
+        drained=sched.drained, rc=0, **compiles)
     if fixed and not sched.drained:
         print("[serve] FAIL: workload not drained")
-        return 1
-    if args.fail_on_abort and sched.stats.aborts:
+        summary["rc"] = 1
+    elif args.fail_on_abort and sched.stats.aborts:
         print(f"[serve] FAIL: {sched.stats.aborts} allocator ABORT(s) "
               "surfaced (--fail-on-abort)")
-        return 1
-    return 0
+        summary["rc"] = 1
+    return summary
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    CC.enable_compile_cache()
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    if args.probe_strategy != cfg.probe_strategy:
+        cfg = dataclasses.replace(cfg, probe_strategy=args.probe_strategy)
+    if args.telemetry:
+        cfg = dataclasses.replace(cfg, telemetry=True)
+    rules = serve_rules_for(cfg, make_serve_mesh())
+    params, _ = init_params(cfg, jax.random.PRNGKey(args.seed), rules)
+    return run(cfg, params, args, rules=rules)["rc"]
 
 
 if __name__ == "__main__":

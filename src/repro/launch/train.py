@@ -18,6 +18,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.dist import fault_tolerance as FT
 from repro.dist import sharding as SH
+from repro.launch.compile_cache import enable_compile_cache
 from repro.training import checkpoint as CKPT
 from repro.training import data as DATA
 from repro.training import train_step as TS
@@ -97,6 +98,7 @@ def main():
     ap.add_argument("--dedup", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     runner = TrainRunner(cfg, ckpt_dir=args.ckpt_dir,

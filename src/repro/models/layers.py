@@ -335,9 +335,11 @@ def attn_qkv_decode(p, x):
 
 
 def attn_out_decode(p, o):
-    """Single-token out projection: o [B, H, hd] -> [B, d].  On a TP head
-    shard this is the row-parallel half — the caller psums over ``model``."""
-    return jnp.einsum("bhk,hkd->bd", o, p["wo"])
+    """Single-token out projection: o [B, H, hd] -> f32 [B, d].  On a TP
+    head shard this is the row-parallel half: the caller psums over
+    ``model`` and rounds to the activation dtype once, after the sum."""
+    return jnp.einsum("bhk,hkd->bd", o, p["wo"],
+                      preferred_element_type=jnp.float32)
 
 
 def kv_head_slice(k, v, shard, kv_rep: int):
@@ -402,10 +404,19 @@ def mlp_init(key, d: int, d_ff: int, dtype):
     return p, a
 
 
-def mlp_apply(p, x):
+def mlp_partial(p, x):
+    """SwiGLU MLP with the f32 sum of its row-parallel ``wo`` projection:
+    on a d_ff shard the caller psums it and rounds once, after the sum
+    (rounding each shard's partial apart drifts the sharded paths away
+    from one chip's result)."""
     g = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, p["wi_gate"]))
     u = jnp.einsum("bsd,df->bsf", x, p["wi_up"])
-    return jnp.einsum("bsf,fd->bsd", g * u, p["wo"])
+    return jnp.einsum("bsf,fd->bsd", g * u, p["wo"],
+                      preferred_element_type=jnp.float32)
+
+
+def mlp_apply(p, x):
+    return mlp_partial(p, x).astype(x.dtype)
 
 
 def block_init(key, cfg, dtype, d_ff: Optional[int] = None):

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import ctx
-from repro.dist.compat import axis_size, shard_map
+from repro.dist.compat import shard_map
 from repro.models import nn
 
 def moe_init(key, cfg, dtype):
@@ -119,7 +119,10 @@ def _moe_local(x, router, wig, wiu, wo, *, k: int, E: int, E_local: int,
     y = jnp.zeros((T + 1, d), jnp.float32).at[src.reshape(-1)].add(
         (out * gate_buf[..., None]).astype(jnp.float32).reshape(-1, d),
         mode="drop")
-    return y[:T].astype(x.dtype), aux
+    # f32 partials: callers round to x.dtype once, after any psum —
+    # rounding each shard's partial apart made the sharded paths drift by
+    # enough to flip a near-tie router choice one layer down
+    return y[:T], aux
 
 
 def moe_apply(p, x, cfg) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -136,7 +139,7 @@ def moe_apply(p, x, cfg) -> Tuple[jnp.ndarray, jnp.ndarray]:
                             p["wi_up"], p["wo"], k=k, E=E, E_local=E,
                             e_offset=0,
                             C=_capacity(B * S, k, E, cfg.moe_capacity_factor))
-        return y.reshape(B, S, d), aux
+        return y.reshape(B, S, d).astype(x.dtype), aux
     if not ep:
         # DP mapping (§Perf): tokens sharded over EVERY axis, all experts
         # local (weights FSDP-gathered per layer by GSPMD outside) — no
@@ -161,7 +164,7 @@ def moe_apply(p, x, cfg) -> Tuple[jnp.ndarray, jnp.ndarray]:
             y, aux = _moe_local(x_l.reshape(Bl * S, d), router, wig, wiu,
                                 wo, k=k, E=E, E_local=E, e_offset=0, C=C)
             aux = jax.lax.pmean(aux, all_axes)
-            return y.reshape(Bl, S, d), aux
+            return y.reshape(Bl, S, d).astype(x_l.dtype), aux
 
         return _dp(x, p["router"], p["wi_gate"], p["wi_up"], p["wo"])
 
@@ -202,7 +205,7 @@ def moe_apply(p, x, cfg) -> Tuple[jnp.ndarray, jnp.ndarray]:
         aux = jax.lax.psum(aux, "model") / tp
         if dp_axes and not serve:
             aux = jax.lax.pmean(aux, dp_axes)
-        return y.reshape(Bl, S, d), aux
+        return y.reshape(Bl, S, d).astype(x_l.dtype), aux
 
     return _sharded(x, p["router"], p["wi_gate"], p["wi_up"], p["wo"])
 
@@ -216,14 +219,14 @@ def moe_decode_local(p, x, cfg) -> jnp.ndarray:
     load-balance loss is dropped (decode never trains the router)."""
     B, S, d = x.shape
     E, k = cfg.num_experts, cfg.experts_per_token
-    tp = axis_size("model")
+    tp = jax.lax.axis_size("model")
     E_local = E // tp
     e_off = jax.lax.axis_index("model") * E_local
     C = _capacity(B * S, k, E, cfg.moe_capacity_factor)
     y, _ = _moe_local(x.reshape(B * S, d), p["router"], p["wi_gate"],
                       p["wi_up"], p["wo"], k=k, E=E, E_local=E_local,
                       e_offset=e_off, C=C)
-    return jax.lax.psum(y.reshape(B, S, d), "model")
+    return jax.lax.psum(y.reshape(B, S, d), "model").astype(x.dtype)
 
 
 def moe_flops_per_token(cfg) -> int:

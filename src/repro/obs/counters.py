@@ -50,8 +50,9 @@ class Counters(NamedTuple):
 
     @classmethod
     def zeros(cls) -> "Counters":
-        z = jnp.zeros((), jnp.int32)
-        return cls(*([z] * len(cls._fields)))
+        # one buffer per leaf: the serving megastep donates the state, and
+        # a buffer shared by two leaves cannot be donated twice
+        return cls(*(jnp.zeros((), jnp.int32) for _ in cls._fields))
 
     @classmethod
     def axes(cls) -> "Counters":

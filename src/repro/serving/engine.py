@@ -455,13 +455,14 @@ def _rope_single(cfg, x, positions, mrope=None):
     return out[:, 0]
 
 
-def _paged_attn_chip(cfg, x, ap, pool_k_l, pool_v_l, scales_l, lp_tree,
+def _paged_attn_chip(cfg, x, ap, pools, scales, layer, lp_tree,
                      write_slot, positions, mrope, bt, *, axes_names, mesh,
                      page_size, kv_sharded, q_sharded, fused=False,
                      interpret=False):
-    """Runs per chip (inside shard_map or standalone)."""
+    """Runs per chip (inside shard_map or standalone).  ``pools`` are the
+    chip's pages of every attention layer; ``layer`` picks this one."""
     B = x.shape[0]
-    npr = pool_k_l.shape[0]
+    npr = pools.k.shape[1]
     chip = _chip_idx(axes_names, mesh) if axes_names else jnp.int32(0)
 
     q, k, v = L.attn_qkv_decode(ap, x[:, 0])
@@ -473,23 +474,23 @@ def _paged_attn_chip(cfg, x, ap, pool_k_l, pool_v_l, scales_l, lp_tree,
     q = _rope_single(cfg, q, positions, mrope)
     k = _rope_single(cfg, k, positions, mrope)
 
-    pool_k_l, pool_v_l, scales_l = paged.write_token_kv(
-        pool_k_l, pool_v_l, k, v, write_slot, positions, chip, npr,
-        page_size, scales=scales_l)
+    pools, scales = paged.write_token_kv(
+        pools, scales, k, v, write_slot, positions, chip, npr, page_size,
+        layer)
 
     n_kv, G = cfg.n_kv, cfg.n_q // cfg.n_kv
     if fused:
         # one Pallas dispatch: in-kernel block-table walk + double-buffered
         # page DMA + attention partials (kernels/fused_decode)
         local_bt = _local_block_table(bt, chip, npr)
-        o, m, l = fused_decode_kernel(q, pool_k_l, pool_v_l, local_bt,
-                                      positions, scales=scales_l,
+        o, m, l = fused_decode_kernel(q, pools.k, pools.v, local_bt,
+                                      positions, layer=layer, scales=scales,
                                       partials=True, interpret=interpret)
     else:
         lp = paged.LocalPages(*(t[0] for t in lp_tree))
         qg = q.reshape(B, n_kv, G, cfg.hd)
-        o, m, l = paged.attend_local(qg, pool_k_l, pool_v_l, lp, positions,
-                                     page_size, scales=scales_l)
+        o, m, l = paged.attend_local(qg, pools, scales, layer, lp,
+                                     positions, page_size)
     out = paged.merge_global(o, m, l, axes_names)    # [B,kv,G,hd] f32
     out = out.reshape(B, cfg.n_q, cfg.hd).astype(x.dtype)
 
@@ -500,24 +501,24 @@ def _paged_attn_chip(cfg, x, ap, pool_k_l, pool_v_l, scales_l, lp_tree,
         y = jax.lax.psum(L.attn_out_decode(ap, my), "model")
     else:
         y = L.attn_out_decode(ap, out)
-    if scales_l is None:
-        scales_l = (jnp.zeros((), jnp.bfloat16),) * 2   # dummy pytree
-    return y[:, None], pool_k_l, pool_v_l, scales_l
+    return y.astype(x.dtype)[:, None], pools, scales
 
 
-def paged_attn_op(cfg, rules, x, ap, pool_k_l, pool_v_l, lp_arrays,
+def paged_attn_op(cfg, rules, x, ap, pools, scales, layer, lp_arrays,
                   write_slot, positions, mrope=None,
-                  page_size: int = DEFAULT_PAGE_SIZE, scales_l=None,
-                  bt=None, fused: bool = False, interpret: bool = False):
-    """x [B,1,d]; pools [n_pages,...]; lp_arrays: LocalPages as [n_chips,CAP]
-    arrays (None when ``fused`` — the kernel walks the raw block table
-    ``bt`` int32[B, maxP] instead).  Returns (attn_out [B,1,d], pool_k',
-    pool_v', scales')."""
+                  page_size: int = DEFAULT_PAGE_SIZE, bt=None,
+                  fused: bool = False, interpret: bool = False):
+    """x [B,1,d]; pools PagedPools [L, n_pages, ...] of every attention
+    layer, ``layer`` the one this call attends (and writes); ``scales``
+    PoolScales for int8 pools, else None; lp_arrays: LocalPages as
+    [n_chips,CAP] arrays (None when ``fused`` — the kernel walks the raw
+    block table ``bt`` int32[B, maxP] instead).  Returns (attn_out [B,1,d],
+    pools', scales')."""
     if rules is None:
         lp_tree = (None if lp_arrays is None
                    else tuple(t[:1] for t in lp_arrays))
         return _paged_attn_chip(
-            cfg, x, ap, pool_k_l, pool_v_l, scales_l, lp_tree, write_slot,
+            cfg, x, ap, pools, scales, layer, lp_tree, write_slot,
             positions, mrope, bt, axes_names=(), mesh=None,
             page_size=page_size, kv_sharded=False, q_sharded=False,
             fused=fused, interpret=interpret)
@@ -537,8 +538,11 @@ def paged_attn_op(cfg, rules, x, ap, pool_k_l, pool_v_l, lp_arrays,
             "bq": P("model", None) if q_sharded else P(),
             "bk": P("model", None) if kv_sharded else P(),
             "bv": P("model", None) if kv_sharded else P()})
-    pool_spec = P(axes_names, None, None, None)
-    scale_spec = P(axes_names, None, None)
+    pool_spec = P(None, axes_names, None, None, None)
+    pools_spec = paged.PagedPools(k=pool_spec, v=pool_spec)
+    scale_spec = P(None, axes_names, None, None)
+    scales_spec = (None if scales is None
+                   else paged.PoolScales(k=scale_spec, v=scale_spec))
     lp_specs = (None if lp_arrays is None
                 else tuple(P(axes_names, None) for _ in lp_arrays))
 
@@ -546,20 +550,15 @@ def paged_attn_op(cfg, rules, x, ap, pool_k_l, pool_v_l, lp_arrays,
         _paged_attn_chip, cfg, axes_names=axes_names, mesh=mesh,
         page_size=page_size, kv_sharded=kv_sharded, q_sharded=q_sharded,
         fused=fused, interpret=interpret)
-    scales_spec = ((scale_spec, scale_spec) if scales_l is not None
-                   else None)
-    out_scales_spec = (scales_spec if scales_l is not None
-                       else (P(), P()))
     mapped = shard_map(
         fn, mesh=mesh,
-        in_specs=(P(), ap_specs, pool_spec, pool_spec, scales_spec,
-                  lp_specs, P(), P(),
-                  P() if mrope is not None else None,
+        in_specs=(P(), ap_specs, pools_spec, scales_spec, P(), lp_specs,
+                  P(), P(), P() if mrope is not None else None,
                   P() if bt is not None else None),
-        out_specs=(P(), pool_spec, pool_spec, out_scales_spec),
+        out_specs=(P(), pools_spec, scales_spec),
         check_vma=False)
-    return mapped(x, ap, pool_k_l, pool_v_l, scales_l, lp_arrays,
-                  write_slot, positions, mrope, bt)
+    return mapped(x, ap, pools, scales, layer, lp_arrays, write_slot,
+                  positions, mrope, bt)
 
 
 def compact_op(rules, slots, n_pages: int, cap: int):
@@ -610,7 +609,8 @@ def _ring_attn(cfg, x, ap, ring_k_l, ring_v_l, ring_pos, positions):
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgw,bwkd->bkgd", p, ring_v_l.astype(jnp.float32))
     o = o.reshape(B, cfg.n_q, cfg.hd).astype(x.dtype)
-    return L.attn_out_decode(ap, o)[:, None], ring_k_l, ring_v_l
+    return (L.attn_out_decode(ap, o).astype(x.dtype)[:, None], ring_k_l,
+            ring_v_l)
 
 
 # ---------------------------------------------------------------------------
@@ -629,7 +629,7 @@ def _cross_attn_decode(cfg, x, cp, ck, cv):
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgs,bskd->bkgd", p, cv.astype(jnp.float32))
     o = o.reshape(B, cfg.n_q, cfg.hd).astype(x.dtype)
-    return L.attn_out_decode(cp, o)[:, None]
+    return L.attn_out_decode(cp, o).astype(x.dtype)[:, None]
 
 
 # ---------------------------------------------------------------------------
@@ -752,8 +752,8 @@ def _qkv_decode_shard(ap, x, kv_rep: int):
     return q, k, v
 
 
-def _paged_attn_shard(cfg, x, ap, pk, pv, scales, lp, write_slot, positions,
-                      mrope, *, chip_pd, npr, page_size, pd_axes,
+def _paged_attn_shard(cfg, x, ap, pools, scales, layer, lp, write_slot,
+                      positions, mrope, *, chip_pd, npr, page_size, pd_axes,
                       kv_rep=1, fused_bt=None, interpret=False):
     """One attention sublayer inside the fused manual region, local head
     shard end-to-end: column-parallel QKV, KV write into the chip's own
@@ -766,28 +766,26 @@ def _paged_attn_shard(cfg, x, ap, pk, pv, scales, lp, write_slot, positions,
     q, k, v = _qkv_decode_shard(ap, x[:, 0], kv_rep)
     q = _rope_single(cfg, q, positions, mrope)
     k = _rope_single(cfg, k, positions, mrope)
-    pk, pv, scales = paged.write_token_kv(pk, pv, k, v, write_slot,
-                                          positions, chip_pd, npr,
-                                          page_size, scales=scales)
+    pools, scales = paged.write_token_kv(pools, scales, k, v, write_slot,
+                                         positions, chip_pd, npr, page_size,
+                                         layer)
     kv_l = k.shape[1]                              # n_kv·rep / tp
     G_l = q.shape[1] // kv_l                       # local group size
     if fused_bt is not None:
         # one Pallas dispatch per layer: in-kernel walk of the chip-local
         # raw block table + double-buffered page DMA (kernels/fused_decode);
         # same (o, m, l) partials contract as paged.attend_local
-        o, m, l = fused_decode_kernel(q, pk, pv, fused_bt, positions,
-                                      scales=scales, partials=True,
-                                      interpret=interpret)
+        o, m, l = fused_decode_kernel(q, pools.k, pools.v, fused_bt,
+                                      positions, layer=layer, scales=scales,
+                                      partials=True, interpret=interpret)
     else:
         qg = q.reshape(B, kv_l, G_l, cfg.hd)       # grouping is head-local
-        o, m, l = paged.attend_local(qg, pk, pv, lp, positions, page_size,
-                                     scales=scales)
+        o, m, l = paged.attend_local(qg, pools, scales, layer, lp,
+                                     positions, page_size)
     out = paged.merge_global(o, m, l, pd_axes)     # heads never cross chips
     out = out.reshape(B, kv_l * G_l, cfg.hd).astype(x.dtype)
-    y = jax.lax.psum(L.attn_out_decode(ap, out), "model")
-    if scales is None:
-        scales = (jnp.zeros((), jnp.bfloat16),) * 2   # dummy pytree
-    return y[:, None], pk, pv, scales
+    y = jax.lax.psum(L.attn_out_decode(ap, out), "model").astype(x.dtype)
+    return y[:, None], pools, scales
 
 
 def _ring_attn_shard(cfg, x, ap, ring_k_l, ring_v_l, ring_pos, positions,
@@ -818,7 +816,7 @@ def _ring_attn_shard(cfg, x, ap, ring_k_l, ring_v_l, ring_pos, positions,
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgw,bwkd->bkgd", p, ring_v_l.astype(jnp.float32))
     o = o.reshape(B, kv_l * G_l, cfg.hd).astype(x.dtype)
-    y = jax.lax.psum(L.attn_out_decode(ap, o), "model")
+    y = jax.lax.psum(L.attn_out_decode(ap, o), "model").astype(x.dtype)
     return y[:, None], ring_k_l, ring_v_l
 
 
@@ -910,29 +908,25 @@ def _manual_decode_parts(cfg, *, S_max: int, rules,
                                          ssm_axis="model" if ssm_tp
                                          else None)
         else:
-            sk, sv = _scale_xs(cfg, state, cfg.num_layers)
-
-            def layer(x, xs):
-                lpar, pk, pv, sk_l, sv_l = xs
-                h, pk, pv, sc = attn(
-                    nn.rmsnorm(lpar["ln1"], x), lpar["attn"], pk, pv,
-                    _scales_in(cfg, sk_l, sv_l), mrope=mrope)
+            def layer(carry, xs):
+                x, pools, scales = carry
+                lpar, li = xs
+                h, pools, scales = attn(
+                    nn.rmsnorm(lpar["ln1"], x), lpar["attn"], pools,
+                    scales, li, mrope=mrope)
                 x = x + h
                 xn = nn.rmsnorm(lpar["ln2"], x)
                 if cfg.family == "moe":
                     y = MOE.moe_decode_local(lpar["moe"], xn, cfg)
                 else:
                     y = TP.mlp_decode_manual(lpar["mlp"], xn)
-                return x + y, (pk, pv) + tuple(sc)
+                return (x + y, pools, scales), None
 
-            x_out, (pk, pv, sk2, sv2) = jax.lax.scan(
-                layer, x, (params["layers"], state["pools"].k,
-                           state["pools"].v, sk, sv),
+            (x_out, pools, scales), _ = jax.lax.scan(
+                layer, (x, state["pools"], state.get("pool_scales")),
+                (params["layers"], jnp.arange(cfg.num_layers)),
                 unroll=cfg.scan_unroll)
-            new_state["pools"] = paged.PagedPools(k=pk, v=pv)
-            if cfg.kv_cache_dtype == "int8":
-                new_state["pool_scales"] = paged.PoolScales(k=sk2,
-                                                            v=sv2)
+            _set_pools(new_state, pools, scales)
         x_out = nn.rmsnorm(params["final_norm"], x_out)
         logits = TP.logits_decode_manual(cfg, params, x_out,
                                          vocab_sharded=vocab_sharded)
@@ -1074,10 +1068,10 @@ def _gemma_layers_shard(cfg, params, state, new_state, x, attn, positions,
     B, W = state["ring_pos"].shape
     ring_k = state["ring_k"].reshape((ng, pat) + state["ring_k"].shape[1:])
     ring_v = state["ring_v"].reshape((ng, pat) + state["ring_v"].shape[1:])
-    sk, sv = _scale_xs(cfg, state, ng)
 
-    def body(x, xs):
-        grp, rks, rvs, pk, pv, sk_l, sv_l = xs
+    def body(carry, xs):
+        x, pools, scales = carry
+        grp, rks, rvs, gi = xs
         new_rk, new_rv = [], []
         for i in range(pat):
             sub = jax.tree.map(lambda t: t[i], grp)
@@ -1090,23 +1084,21 @@ def _gemma_layers_shard(cfg, params, state, new_state, x, attn, positions,
             new_rk.append(rk2)
             new_rv.append(rv2)
         sub = jax.tree.map(lambda t: t[pat], grp)
-        h, pk, pv, sc = attn(nn.rmsnorm(sub["ln1"], x), sub["attn"], pk,
-                             pv, _scales_in(cfg, sk_l, sv_l), mrope=None)
+        h, pools, scales = attn(nn.rmsnorm(sub["ln1"], x), sub["attn"],
+                                pools, scales, gi, mrope=None)
         x = x + h
         x = x + TP.mlp_decode_manual(sub["mlp"], nn.rmsnorm(sub["ln2"], x))
-        return x, (jnp.stack(new_rk), jnp.stack(new_rv), pk, pv) + tuple(sc)
+        return (x, pools, scales), (jnp.stack(new_rk), jnp.stack(new_rv))
 
-    x, (rk, rv, pk, pv, sk2, sv2) = jax.lax.scan(
-        body, x, (stacked, ring_k, ring_v, state["pools"].k,
-                  state["pools"].v, sk, sv),
+    (x, pools, scales), (rk, rv) = jax.lax.scan(
+        body, (x, state["pools"], state.get("pool_scales")),
+        (stacked, ring_k, ring_v, jnp.arange(ng)),
         unroll=ng if cfg.unroll_layers else 1)
     new_state["ring_k"] = rk.reshape((ng * pat,) + rk.shape[2:])
     new_state["ring_v"] = rv.reshape((ng * pat,) + rv.shape[2:])
     new_state["ring_pos"] = state["ring_pos"].at[
         jnp.arange(B), positions % W].set(positions)
-    new_state["pools"] = paged.PagedPools(k=pk, v=pv)
-    if cfg.kv_cache_dtype == "int8":
-        new_state["pool_scales"] = paged.PoolScales(k=sk2, v=sv2)
+    _set_pools(new_state, pools, scales)
     return x
 
 
@@ -1122,24 +1114,17 @@ def _hybrid_layers_shard(cfg, params, state, new_state, x, attn,
     every = cfg.shared_attn_every
     n_inv = cfg.num_layers // every
     sp = params["shared"]
-    pk, pv = state["pools"].k, state["pools"].v
-    sk, sv = _scale_xs(cfg, state, n_inv)
+    pools, scales = state["pools"], state.get("pool_scales")
     new_ssm_chunks = []
-    pk_out, pv_out, sk_out, sv_out = [], [], [], []
     for g in range(n_inv):
         x, s2 = HY.mamba_decode_chunk(cfg, params["layers"], state["ssm"],
                                       x, g * every, (g + 1) * every,
                                       tp_axis=ssm_axis)
         new_ssm_chunks.append(s2)
-        h, pk_g, pv_g, sc = attn(nn.rmsnorm(sp["ln1"], x), sp["attn"],
-                                 pk[g], pv[g],
-                                 _scales_in(cfg, sk[g], sv[g]), mrope=None)
+        h, pools, scales = attn(nn.rmsnorm(sp["ln1"], x), sp["attn"],
+                                pools, scales, g, mrope=None)
         x = x + h
         x = x + TP.mlp_decode_manual(sp["mlp"], nn.rmsnorm(sp["ln2"], x))
-        pk_out.append(pk_g)
-        pv_out.append(pv_g)
-        sk_out.append(sc[0])
-        sv_out.append(sc[1])
     rem = cfg.num_layers - n_inv * every
     if rem:
         x, s2 = HY.mamba_decode_chunk(cfg, params["layers"], state["ssm"],
@@ -1152,11 +1137,7 @@ def _hybrid_layers_shard(cfg, params, state, new_state, x, attn,
         jax.tree.map(lambda *ts: jnp.concatenate(ts, axis=0),
                      *new_ssm_chunks),
         state["ssm"], state["active"] & ~new_state["aborted"])
-    new_state["pools"] = paged.PagedPools(k=jnp.stack(pk_out),
-                                          v=jnp.stack(pv_out))
-    if cfg.kv_cache_dtype == "int8":
-        new_state["pool_scales"] = paged.PoolScales(k=jnp.stack(sk_out),
-                                                    v=jnp.stack(sv_out))
+    _set_pools(new_state, pools, scales)
     return x
 
 
@@ -1196,17 +1177,11 @@ def _freeze_lanes(new_tree, old_tree, act):
     return jax.tree.map(sel, new_tree, old_tree)
 
 
-def _scale_xs(cfg, state, n_layers):
-    """Per-layer scale arrays for the scan xs (dummies when bf16 pools)."""
-    if cfg.kv_cache_dtype == "int8":
-        sc = state["pool_scales"]
-        return sc.k, sc.v
-    z = jnp.zeros((n_layers,), jnp.bfloat16)
-    return z, z
-
-
-def _scales_in(cfg, sk_l, sv_l):
-    return (sk_l, sv_l) if cfg.kv_cache_dtype == "int8" else None
+def _set_pools(new_state, pools, scales):
+    """Store the layer loop's pools (and int8 scales) in the new state."""
+    new_state["pools"] = pools
+    if scales is not None:
+        new_state["pool_scales"] = scales
 
 
 def _mlp_or_moe(cfg, p, x):
@@ -1240,33 +1215,30 @@ def _serve_step_impl(cfg, params, state, tokens, positions, mrope,
                                                    page_size, bt=bt,
                                                    fused=fused,
                                                    interpret=interp)
-            new_state["pools"] = pools
             new_state["ring_k"], new_state["ring_v"], new_state["ring_pos"] \
                 = ring
-            if scales is not None:
-                new_state["pool_scales"] = scales
         else:
-            sk, sv = _scale_xs(cfg, state, cfg.num_layers)
-
-            def body(x, xs):
-                lp_params, pk, pv, sk_l, sv_l = xs
-                h, pk, pv, sc = paged_attn_op(
-                    cfg, rules, nn.rmsnorm(lp_params["ln1"], x), lp_params["attn"],
-                    pk, pv, lp, write_slot, positions, mrope, page_size,
-                    scales_l=_scales_in(cfg, sk_l, sv_l),
-                    bt=bt if fused else None, fused=fused, interpret=interp)
+            # the pools ride the layer loop's carry, so each layer's KV
+            # write updates them in place (as scan xs/ys every step would
+            # copy the whole pool into a fresh stacked output)
+            def body(carry, xs):
+                x, pools, scales = carry
+                lp_params, li = xs
+                h, pools, scales = paged_attn_op(
+                    cfg, rules, nn.rmsnorm(lp_params["ln1"], x),
+                    lp_params["attn"], pools, scales, li, lp, write_slot,
+                    positions, mrope, page_size, bt=bt if fused else None,
+                    fused=fused, interpret=interp)
                 x = x + h
                 x = x + _mlp_or_moe(cfg, lp_params,
                                     nn.rmsnorm(lp_params["ln2"], x))
-                return x, (pk, pv) + tuple(sc)
+                return (x, pools, scales), None
 
-            x, (pk, pv, sk2, sv2) = jax.lax.scan(
-                body, x, (params["layers"], state["pools"].k,
-                          state["pools"].v, sk, sv),
+            (x, pools, scales), _ = jax.lax.scan(
+                body, (x, state["pools"], state.get("pool_scales")),
+                (params["layers"], jnp.arange(cfg.num_layers)),
                 unroll=cfg.scan_unroll)
-            new_state["pools"] = paged.PagedPools(k=pk, v=pv)
-            if cfg.kv_cache_dtype == "int8":
-                new_state["pool_scales"] = paged.PoolScales(k=sk2, v=sv2)
+        _set_pools(new_state, pools, scales)
 
     elif cfg.family == "ssm":
         def body(x, xs):
@@ -1289,26 +1261,19 @@ def _serve_step_impl(cfg, params, state, tokens, positions, mrope,
         n_inv = cfg.num_layers // every
 
         new_ssm_chunks = []
-        pk, pv = state["pools"].k, state["pools"].v
-        sk, sv = _scale_xs(cfg, state, n_inv)
-        pk_out, pv_out, sk_out, sv_out = [], [], [], []
+        pools, scales = state["pools"], state.get("pool_scales")
         sp = params["shared"]
         for g in range(n_inv):
             x, s2 = HY.mamba_decode_chunk(cfg, params["layers"],
                                           state["ssm"], x,
                                           g * every, (g + 1) * every)
             new_ssm_chunks.append(s2)
-            h, pk_g, pv_g, sc = paged_attn_op(
-                cfg, rules, nn.rmsnorm(sp["ln1"], x), sp["attn"],
-                pk[g], pv[g], lp, write_slot, positions, None, page_size,
-                scales_l=_scales_in(cfg, sk[g], sv[g]),
+            h, pools, scales = paged_attn_op(
+                cfg, rules, nn.rmsnorm(sp["ln1"], x), sp["attn"], pools,
+                scales, g, lp, write_slot, positions, None, page_size,
                 bt=bt if fused else None, fused=fused, interpret=interp)
             x = x + h
             x = x + L.mlp_apply(sp["mlp"], nn.rmsnorm(sp["ln2"], x))
-            pk_out.append(pk_g)
-            pv_out.append(pv_g)
-            sk_out.append(sc[0])
-            sv_out.append(sc[1])
         rem = cfg.num_layers - n_inv * every
         if rem:
             x, s2 = HY.mamba_decode_chunk(cfg, params["layers"],
@@ -1320,11 +1285,7 @@ def _serve_step_impl(cfg, params, state, tokens, positions, mrope,
         new_state["ssm"] = _freeze_lanes(
             jax.tree.map(lambda *ts: jnp.concatenate(ts, axis=0),
                          *new_ssm_chunks), state["ssm"], act & ~aborts)
-        new_state["pools"] = paged.PagedPools(k=jnp.stack(pk_out),
-                                              v=jnp.stack(pv_out))
-        if cfg.kv_cache_dtype == "int8":
-            new_state["pool_scales"] = paged.PoolScales(
-                k=jnp.stack(sk_out), v=jnp.stack(sv_out))
+        _set_pools(new_state, pools, scales)
 
     elif cfg.family == "encdec":
         table, write_slot, aborts, bt, lp = _page_ops(
@@ -1333,28 +1294,26 @@ def _serve_step_impl(cfg, params, state, tokens, positions, mrope,
         new_state["table"] = table
         new_state["block_table"] = bt
 
-        sk, sv = _scale_xs(cfg, state, cfg.num_layers)
-
-        def body(x, xs):
-            lp_params, pk, pv, sk_l, sv_l, ck, cv = xs
-            h, pk, pv, sc = paged_attn_op(
+        def body(carry, xs):
+            x, pools, scales = carry
+            lp_params, li, ck, cv = xs
+            h, pools, scales = paged_attn_op(
                 cfg, rules, nn.rmsnorm(lp_params["ln1"], x),
-                lp_params["attn"], pk, pv, lp, write_slot, positions, None,
-                page_size, scales_l=_scales_in(cfg, sk_l, sv_l))
+                lp_params["attn"], pools, scales, li, lp, write_slot,
+                positions, None, page_size)
             x = x + h
             x = x + _cross_attn_decode(cfg, nn.rmsnorm(lp_params["ln_cross"], x),
                                        lp_params["cross"], ck, cv)
             x = x + L.mlp_apply(lp_params["mlp"],
                                 nn.rmsnorm(lp_params["ln2"], x))
-            return x, (pk, pv) + tuple(sc)
+            return (x, pools, scales), None
 
-        x, (pk, pv, sk2, sv2) = jax.lax.scan(
-            body, x, (params["decoder"], state["pools"].k, state["pools"].v,
-                      sk, sv, state["cross_k"], state["cross_v"]),
+        (x, pools, scales), _ = jax.lax.scan(
+            body, (x, state["pools"], state.get("pool_scales")),
+            (params["decoder"], jnp.arange(cfg.num_layers),
+             state["cross_k"], state["cross_v"]),
             unroll=cfg.scan_unroll)
-        new_state["pools"] = paged.PagedPools(k=pk, v=pv)
-        if cfg.kv_cache_dtype == "int8":
-            new_state["pool_scales"] = paged.PoolScales(k=sk2, v=sv2)
+        _set_pools(new_state, pools, scales)
     else:
         raise ValueError(cfg.family)
 
@@ -1411,10 +1370,9 @@ def _gemma_layers(cfg, params, state, x, lp, write_slot, positions, rules,
     ring_k = state["ring_k"].reshape((ng, pat) + state["ring_k"].shape[1:])
     ring_v = state["ring_v"].reshape((ng, pat) + state["ring_v"].shape[1:])
 
-    sk, sv = _scale_xs(cfg, state, ng)
-
-    def body(x, xs):
-        grp, rks, rvs, pk, pv, sk_l, sv_l = xs
+    def body(carry, xs):
+        x, pools, scales = carry
+        grp, rks, rvs, gi = xs
         new_rk, new_rv = [], []
         for i in range(pat):
             sub = jax.tree.map(lambda t: t[i], grp)
@@ -1426,24 +1384,20 @@ def _gemma_layers(cfg, params, state, x, lp, write_slot, positions, rules,
             new_rk.append(rk2)
             new_rv.append(rv2)
         sub = jax.tree.map(lambda t: t[pat], grp)
-        h, pk, pv, sc = paged_attn_op(cfg, rules, nn.rmsnorm(sub["ln1"], x),
-                                      sub["attn"], pk, pv, lp, write_slot,
-                                      positions, None, page_size,
-                                      scales_l=_scales_in(cfg, sk_l, sv_l),
-                                      bt=bt if fused else None, fused=fused,
-                                      interpret=interpret)
+        h, pools, scales = paged_attn_op(
+            cfg, rules, nn.rmsnorm(sub["ln1"], x), sub["attn"], pools,
+            scales, gi, lp, write_slot, positions, None, page_size,
+            bt=bt if fused else None, fused=fused, interpret=interpret)
         x = x + h
         x = x + L.mlp_apply(sub["mlp"], nn.rmsnorm(sub["ln2"], x))
-        return x, (jnp.stack(new_rk), jnp.stack(new_rv), pk, pv) + tuple(sc)
+        return (x, pools, scales), (jnp.stack(new_rk), jnp.stack(new_rv))
 
-    x, (rk, rv, pk, pv, sk2, sv2) = jax.lax.scan(
-        body, x, (stacked, ring_k, ring_v, state["pools"].k,
-                  state["pools"].v, sk, sv),
+    (x, pools, scales), (rk, rv) = jax.lax.scan(
+        body, (x, state["pools"], state.get("pool_scales")),
+        (stacked, ring_k, ring_v, jnp.arange(ng)),
         unroll=ng if cfg.unroll_layers else 1)
     rk = rk.reshape((ng * pat,) + rk.shape[2:])
     rv = rv.reshape((ng * pat,) + rv.shape[2:])
     ring_pos = state["ring_pos"].at[jnp.arange(B), positions % W].set(
         positions)
-    scales = (paged.PoolScales(k=sk2, v=sv2)
-              if cfg.kv_cache_dtype == "int8" else None)
-    return x, paged.PagedPools(k=pk, v=pv), (rk, rv, ring_pos), scales
+    return x, pools, (rk, rv, ring_pos), scales

@@ -110,12 +110,16 @@ def compact_local(slots: jnp.ndarray, chip_idx, npr: int,
                       page=page[:cap], valid=valid[:cap])
 
 
-def write_token_kv(pool_k_l, pool_v_l, k_new, v_new, write_slot, positions,
-                   chip_idx, npr: int, page_size: int, scales=None):
-    """Write one token's K/V [B, n_kv, hd] into the page each sequence's
-    current position maps to (only on the owning chip).  RoPE is applied by
-    the caller BEFORE the write (cache stores rotated keys).  With int8
-    pools, ``scales`` is (k_scale_l, v_scale_l) [npr, psize, kv].
+def write_token_kv(pools: PagedPools, scales: Optional[PoolScales], k_new,
+                   v_new, write_slot, positions, chip_idx, npr: int,
+                   page_size: int, layer):
+    """Write one token's K/V [B, n_kv, hd] of attention layer ``layer``
+    into the page each sequence's current position maps to (only on the
+    owning chip).  RoPE is applied by the caller BEFORE the write (cache
+    stores rotated keys).  The pools stay stacked over layers ([L, npr, PS,
+    kv, hd]) and the write is one small scatter into them: slicing a
+    layer's pool out and stacking it back would copy the whole pool every
+    step.  With int8 pools, ``scales`` holds the per-token sidecars.
 
     ``write_slot = -1`` is the allocator's ABORT/refusal sentinel
     (page_table.AllocStep): such lanes MUST NOT scatter — the clamp below
@@ -124,44 +128,40 @@ def write_token_kv(pool_k_l, pool_v_l, k_new, v_new, write_slot, positions,
     mine = (write_slot >= 0) & (write_slot // npr == chip_idx)
     rows = jnp.where(mine, jnp.clip(write_slot, 0) % npr, npr)  # npr -> drop
     offs = positions % page_size
-    if pool_k_l.dtype == jnp.int8:
+
+    def put(pool, val):
+        return pool.at[layer, rows, offs].set(val.astype(pool.dtype),
+                                              mode="drop")
+
+    if pools.k.dtype == jnp.int8:
         k_q, k_s = quantize_kv(k_new)
         v_q, v_s = quantize_kv(v_new)
-        k_scale_l, v_scale_l = scales
-        pool_k_l = pool_k_l.at[rows, offs].set(k_q, mode="drop")
-        pool_v_l = pool_v_l.at[rows, offs].set(v_q, mode="drop")
-        k_scale_l = k_scale_l.at[rows, offs].set(k_s, mode="drop")
-        v_scale_l = v_scale_l.at[rows, offs].set(v_s, mode="drop")
-        return pool_k_l, pool_v_l, (k_scale_l, v_scale_l)
-    pool_k_l = pool_k_l.at[rows, offs].set(k_new.astype(pool_k_l.dtype),
-                                           mode="drop")
-    pool_v_l = pool_v_l.at[rows, offs].set(v_new.astype(pool_v_l.dtype),
-                                           mode="drop")
-    return pool_k_l, pool_v_l, None
+        return (PagedPools(k=put(pools.k, k_q), v=put(pools.v, v_q)),
+                PoolScales(k=put(scales.k, k_s), v=put(scales.v, v_s)))
+    return PagedPools(k=put(pools.k, k_new), v=put(pools.v, v_new)), scales
 
 
-def attend_local(q_all, pool_k_l, pool_v_l, lp: LocalPages, positions,
-                 page_size: int, scales=None
+def attend_local(q_all, pools: PagedPools, scales: Optional[PoolScales],
+                 layer, lp: LocalPages, positions, page_size: int
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Per-chip partial attention.
+    """Per-chip partial attention of attention layer ``layer``.
 
-    q_all [B, n_kv, G, hd] (grouped query, full batch); pools [npr, psize,
-    n_kv, hd]; positions [B] current decode position per sequence.
+    q_all [B, n_kv, G, hd] (grouped query, full batch); pools [L, npr,
+    psize, n_kv, hd]; positions [B] current decode position per sequence.
     Returns per-sequence partials (o [B,kv,G,hd] f32, m [B,kv,G], l [B,kv,G])
     ready for cross-chip lse merge."""
     B = q_all.shape[0]
-    CAP = lp.rows.shape[0]
-    _, psize, n_kv, hd = pool_k_l.shape
+    _, _, psize, n_kv, hd = pools.k.shape
     scale = 1.0 / math.sqrt(hd)
 
-    k_loc = pool_k_l[lp.rows]                         # [CAP, psize, kv, hd]
-    v_loc = pool_v_l[lp.rows]
-    if pool_k_l.dtype == jnp.int8:
-        k_scale_l, v_scale_l = scales
+    # one gather indexed by (layer, row): no per-layer pool slice is built
+    k_loc = pools.k[layer, lp.rows]                   # [CAP, psize, kv, hd]
+    v_loc = pools.v[layer, lp.rows]
+    if pools.k.dtype == jnp.int8:
         k_loc = (k_loc.astype(jnp.float32)
-                 * k_scale_l[lp.rows].astype(jnp.float32)[..., None])
+                 * scales.k[layer, lp.rows].astype(jnp.float32)[..., None])
         v_loc = (v_loc.astype(jnp.float32)
-                 * v_scale_l[lp.rows].astype(jnp.float32)[..., None])
+                 * scales.v[layer, lp.rows].astype(jnp.float32)[..., None])
     seq_c = jnp.minimum(lp.seq, B - 1)
     q_pages = q_all[seq_c]                            # [CAP, kv, G, hd]
     s = jnp.einsum("ckgd,cskd->ckgs", q_pages.astype(jnp.float32),

@@ -22,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist import compression
 from repro.dist import ctx
-from repro.dist.compat import axis_size, shard_map
+from repro.dist.compat import shard_map
 from repro.models.registry import get_model
 from repro.training import optimizer as opt
 
@@ -126,7 +126,7 @@ def make_train_step_manual_pod(cfg, mesh,
                         lambda g: jax.lax.pmean(g, dp_axes), grads)
                 grads, err2 = compression.tree_compressed_psum(
                     grads, "pod", err_local)
-                npods = axis_size("pod")
+                npods = jax.lax.axis_size("pod")
                 grads = jax.tree.map(lambda g: g / npods, grads)
                 loss = jax.lax.pmean(loss, ("pod",) + dp_axes)
                 params2, opt2, metrics = opt.apply(adamw, state.params,

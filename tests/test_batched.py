@@ -174,11 +174,11 @@ SHARD_TEST = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, numpy as np, jax.numpy as jnp
-from jax.sharding import Mesh
 from repro.core import sharded as SH
 from repro.core.spec import OP_INSERT, OP_DELETE, OP_LOOKUP, step_spec
+from repro.launch.mesh import make_mesh
 
-mesh = Mesh(np.array(jax.devices()).reshape(8), ("model",))
+mesh = make_mesh((8,), ("model",))
 st, apply_fn = SH.make_sharded_table(mesh, "model", m_global=8 * 64,
                                      capacity=32, seed=0)
 rng = np.random.default_rng(0)

@@ -19,11 +19,12 @@ from repro.dist import pipeline as PL
 from repro.dist import tp as TP
 from repro.dist.sharding import ShardingRules, dp_rules, serve_manual_rules, \
     serve_rules, train_rules
+from repro.launch.mesh import make_mesh
 from repro.models import layers as L
 
 
 def _mesh_1x1():
-    return jax.make_mesh((1, 1), ("data", "model"),
+    return make_mesh((1, 1), ("data", "model"),
                          devices=jax.devices()[:1])
 
 
@@ -73,7 +74,7 @@ def test_shard_act_identity_when_spec_replicated():
 
 def _fake_mesh_rules():
     """Rules over an abstract 2x4 mesh (no devices needed for spec logic)."""
-    mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 4)))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     return ShardingRules(mesh=mesh, rules={
         "batch": ("pod", "data"), "heads": ("model",), "kv": ("model",),
         "embed": ("data",), "vocab": ("model",),
@@ -104,7 +105,7 @@ def test_spec_exclude_manual_axes():
 
 def test_axis_for_experts_contract():
     """models/moe.py keys expert parallelism off axis_for("experts", E)."""
-    mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 4)))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     r = train_rules(mesh)
     assert r.axis_for("experts", 8) == "model"
     assert r.axis_for("experts", 6) is None        # 6 % 4 != 0
@@ -169,8 +170,8 @@ def test_decode_manual_tp_gate():
     """decode_manual_tp: tp_impl/mesh/divisibility gating, tp==1 allowed,
     refusal inside an enclosing manual region (serving/engine keys the fused
     decode region off this)."""
-    mesh42 = jax.sharding.AbstractMesh((("data", 4), ("model", 2)))
-    mesh24 = jax.sharding.AbstractMesh((("data", 2), ("model", 4)))
+    mesh42 = jax.sharding.AbstractMesh((4, 2), ("data", "model"))
+    mesh24 = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     dense = get_smoke_config("qwen2.5-32b")          # n_q=8, n_kv=2
     man = dataclasses.replace(dense, tp_impl="manual")
     assert TP.decode_manual_tp(dense, serve_manual_rules(mesh42)) == 0
@@ -236,7 +237,7 @@ def test_serve_manual_rules_pool_layout():
     """The fused-decode layout: pages over (pod, data) only, KV heads over
     model — serve_manual_rules + POOL_AXES_TP must resolve to exactly that."""
     from repro.serving import paged
-    mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 4)))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     r = serve_manual_rules(mesh)
     spec = r.spec(paged.POOL_AXES_TP, (2, 8, 4, 8, 16))
     assert spec == P(None, "data", None, "model")
@@ -266,7 +267,7 @@ def test_compressed_bytes_counts_int8_payload():
 # pipeline (single stage degenerates to sequential; S>1 runs in test_mesh).
 
 def test_pipeline_single_stage_matches_sequential():
-    mesh = jax.make_mesh((1,), ("pod",), devices=jax.devices()[:1])
+    mesh = make_mesh((1,), ("pod",), devices=jax.devices()[:1])
 
     class Cfg:
         num_layers = 4
@@ -288,7 +289,7 @@ def test_pipeline_single_stage_matches_sequential():
 
 
 def test_pipeline_rejects_bad_partition():
-    mesh = jax.make_mesh((1,), ("pod",), devices=jax.devices()[:1])
+    mesh = make_mesh((1,), ("pod",), devices=jax.devices()[:1])
 
     class Cfg:
         num_layers = 4

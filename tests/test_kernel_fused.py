@@ -31,6 +31,7 @@ from repro.kernels.fused_decode import (block_table_slots_ref,
                                         fused_paged_attention,
                                         merge_fused_partials)
 from repro.kernels.probe import probe_lookup, resolved_fraction
+from repro.launch.mesh import make_mesh
 from repro.models.registry import get_model
 from repro.serving import engine as EG
 from repro.serving import page_table as PT
@@ -200,7 +201,7 @@ def test_engine_fused_matches_two_dispatch_gspmd(arch, over):
 def _manual_mesh():
     n = jax.device_count()
     shape = (2, n // 2) if n >= 2 else (1, 1)
-    return jax.make_mesh(shape, ("data", "model"),
+    return make_mesh(shape, ("data", "model"),
                          devices=jax.devices()[:shape[0] * shape[1]])
 
 

@@ -26,6 +26,7 @@ def run_with_devices(script: str, n: int = 8) -> str:
 
 COMMON = """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 assert len(jax.devices()) == 8, jax.devices()
 """
 
@@ -37,7 +38,7 @@ from repro.dist import ctx
 from repro.dist.sharding import train_rules
 from repro.models import moe as MOE
 cfg = get_smoke_config("granite-moe-1b-a400m")   # 4 experts
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 key = jax.random.PRNGKey(0)
 p, a = MOE.moe_init(key, cfg, jnp.float32)
 x = jax.random.normal(key, (4, 8, cfg.d_model), jnp.float32)
@@ -57,7 +58,7 @@ from repro.dist.sharding import serve_rules
 from repro.models.registry import get_model
 from repro.serving import engine as EG
 cfg = get_smoke_config("qwen2.5-32b")   # 8 q heads, kv 2
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rules = serve_rules(mesh)
 model = get_model(cfg)
 params, _ = model.init(cfg, jax.random.PRNGKey(0))
@@ -91,7 +92,7 @@ from repro.dist.sharding import train_rules
 from repro.training import train_step as TS
 from repro.training import data as D
 cfg = get_smoke_config("codeqwen1.5-7b")
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rules = train_rules(mesh)
 state, axes = TS.init_state(cfg, jax.random.PRNGKey(0))
 err = TS.init_pod_error_buffers(state.params, 2)
@@ -114,7 +115,7 @@ print("manual-pod compressed step OK, loss", float(metrics["loss"]))
 def test_pipeline_matches_sequential():
     run_with_devices(COMMON + """
 from repro.dist import pipeline as PL
-mesh = jax.make_mesh((4, 2), ("pod", "data"))
+mesh = make_mesh((4, 2), ("pod", "data"))
 L, d = 8, 16
 key = jax.random.PRNGKey(0)
 ws = jax.random.normal(key, (L, d, d)) * 0.1
@@ -148,7 +149,7 @@ cfg = get_smoke_config("qwen2.5-32b")
 state, axes = TS.init_state(cfg, jax.random.PRNGKey(0))
 CKPT.save({str(tmp_path)!r}, 5, state, axes)
 # restore onto a DIFFERENT mesh shape (elastic resize 8 -> 4+4)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 rules = train_rules(mesh)
 restored, step = CKPT.restore({str(tmp_path)!r}, state, rules=rules)
 assert step == 5
@@ -171,7 +172,7 @@ from repro.dist.sharding import train_rules
 from repro.models import layers as L
 cfg = dataclasses.replace(get_smoke_config("qwen2.5-32b"),
                           dtype="float32", tp_impl="manual")
-mesh = jax.make_mesh((4, 2), ("data", "model"))   # tp=2 divides q=8, kv=2
+mesh = make_mesh((4, 2), ("data", "model"))   # tp=2 divides q=8, kv=2
 key = jax.random.PRNGKey(0)
 p, _ = L.block_init(key, cfg, jnp.float32)
 x = jax.random.normal(jax.random.fold_in(key, 1), (4, 16, cfg.d_model))
@@ -219,7 +220,7 @@ CASES = [
 ]
 for arch, shape, axes, over in CASES:
     cfg = dataclasses.replace(get_smoke_config(arch), **over)
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     model = get_model(cfg)
     params, _ = model.init(cfg, jax.random.PRNGKey(0))
     B, T = 2, 10
@@ -280,7 +281,7 @@ CASES = [
 B, K = 2, 8
 for arch, shape, axes, over in CASES:
     base = dataclasses.replace(get_smoke_config(arch), **over)
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     model = get_model(base)
     params, _ = model.init(base, jax.random.PRNGKey(0))
     tok0 = jax.random.randint(jax.random.PRNGKey(1), (B, 1), 0,
@@ -328,7 +329,7 @@ def test_sharded_dht_roundtrip():
     run_with_devices(COMMON + """
 from repro.core import sharded as SHT
 from repro.core.spec import OP_INSERT, OP_LOOKUP, OP_DELETE
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 st, apply_fn = SHT.make_sharded_table(mesh, "model", m_global=1024,
                                       capacity=64)
 B = 128

@@ -12,6 +12,7 @@ from hypothesis import given, settings, strategies as st
 from repro.configs import get_smoke_config
 from repro.core import batched as BT
 from repro.dist.sharding import serve_manual_rules
+from repro.launch.mesh import make_mesh
 from repro.models.registry import get_model
 from repro.serving import engine as EG
 from repro.serving import page_table as PT
@@ -71,7 +72,7 @@ def test_decode_matches_forward(arch):
 
 
 def _mesh_1x1():
-    return jax.make_mesh((1, 1), ("data", "model"),
+    return make_mesh((1, 1), ("data", "model"),
                          devices=jax.devices()[:1])
 
 
