@@ -94,9 +94,9 @@ class ContinuousBatcher:
         self.state["active"] = jnp.zeros((batch,), bool)  # no lanes seated
         # the state is donated: each megastep updates the KV pools in place
         # instead of holding a second copy of them
-        self.mega_fn = jax.jit(EG.make_serve_megastep(
-            cfg, S_max=max_len, K=self.K, rules=rules, page_size=page_size),
-            donate_argnums=(1,))
+        self._megastep = EG.make_serve_megastep(
+            cfg, S_max=max_len, K=self.K, rules=rules, page_size=page_size)
+        self.mega_fn = jax.jit(self._megastep, donate_argnums=(1,))
         pool = EG.decode_headroom(self.state, strategy=self.strategy)
         self.sched = scheduler or Scheduler(
             slots=batch, page_size=page_size, max_len=max_len,
@@ -113,7 +113,13 @@ class ContinuousBatcher:
                             lambda: EG.fallback_report(cfg, rules))
         self.metrics.source("kernel", lambda: dict(KS.KERNEL_STATS))
         self.metrics.source("probe", lambda: dict(PT.PROBE_STATS))
+        OBS.listen_compiles()
+        self.metrics.source("jit", OBS.compile_stats)
         self._ctr_prev: dict = {}
+        # the megastep's scope map, built when first asked for; published
+        # so that an in-process trace reader can name its device operations
+        self._scopes: dict | None = None
+        OBS.publish_scopes("megastep", self.megastep_scopes)
         # quiet engine degradations (kernel fallbacks, gspmd decode, oracle
         # probe path) surface once at startup, not only in dryrun/CI
         logger.info("engine fallback report: %s",
@@ -205,9 +211,28 @@ class ContinuousBatcher:
                         self._emit("first_token", req=req.req_id)
 
     def _apply_plan(self, plan):
-        st = self.sched
         evict = plan.evict_slots
-        if evict and "table" in self.state:
+        if evict:
+            with OBS.span("serve.free"):
+                self._free_lanes(evict)
+        if plan.grow_to is not None and "table" in self.state:
+            # PROACTIVE Section 4.3 rebuild: before the abort, between
+            # megasteps — the wait-free read path never sees it mid-flight.
+            # Traced as "rebuild" (eager, atomic), NOT "grow": only the
+            # sharded table's lazy resize opens a frozen-old-table window.
+            with OBS.span("serve.rebuild"):
+                self.state = EG.rebuild_page_table(self.state,
+                                                   n_pages=plan.grow_to,
+                                                   strategy=self.strategy)
+            self._emit("rebuild", reason="grow", n_pages=plan.grow_to)
+        if plan.admissions:
+            with OBS.span("serve.admit"):
+                self._admit(plan.admissions)
+
+    def _free_lanes(self, evict):
+        """Delete the evicted lanes' pages, invalidate their block-table
+        rows and deactivate them."""
+        if "table" in self.state:
             mask = np.zeros(self.B, bool)
             mask[evict] = True
             dmask = jnp.asarray(mask)
@@ -224,44 +249,39 @@ class ContinuousBatcher:
                     table_after=self.state["table"])
             self.state["block_table"] = self.pt.invalidate_block_rows(
                 self.state["block_table"], dmask)
-        if evict:
-            active = np.asarray(self.state["active"]).copy()
-            active[evict] = False
-            self.state["active"] = jnp.asarray(active)
-        if plan.grow_to is not None and "table" in self.state:
-            # PROACTIVE Section 4.3 rebuild: before the abort, between
-            # megasteps — the wait-free read path never sees it mid-flight.
-            # Traced as "rebuild" (eager, atomic), NOT "grow": only the
-            # sharded table's lazy resize opens a frozen-old-table window.
-            self.state = EG.rebuild_page_table(self.state,
-                                               n_pages=plan.grow_to,
-                                               strategy=self.strategy)
-            self._emit("rebuild", reason="grow", n_pages=plan.grow_to)
-        if plan.admissions:
-            seq_ids = np.asarray(self.state["seq_ids"]).copy()
-            active = np.asarray(self.state["active"]).copy()
-            aborted = np.asarray(self.state["aborted"]).copy()
-            tokens = np.asarray(self.tokens).copy()
-            self._reset_recurrent_state([s for s, _ in plan.admissions])
-            for slot, req in plan.admissions:
-                known = req.known_tokens()
-                self.lane_known[slot] = known
-                self.lane_stop[slot] = st.stop_of(req)
-                seq_ids[slot] = self.next_seq_id
-                self.next_seq_id += 1
-                self.pos[slot] = 0
-                active[slot] = True
-                aborted[slot] = False
-                tokens[slot, 0] = known[0]
-                # fresh admissions start at pos 0 with no pages, so the
-                # invalidated (-1) block-table rows ARE the correct cache;
-                # an admission carrying prefilled pages would rebuild its
-                # rows from the wait-free lookup (PageTable.rebuild_block_table)
-            self.state["seq_ids"] = jnp.asarray(seq_ids)
-            self.state["active"] = jnp.asarray(active)
-            self.state["aborted"] = jnp.asarray(aborted)
-            self.state["pos"] = jnp.asarray(self.pos)
-            self.tokens = jnp.asarray(tokens)
+        active = np.asarray(self.state["active"]).copy()
+        active[evict] = False
+        self.state["active"] = jnp.asarray(active)
+
+    def _admit(self, admissions):
+        """Seat each admitted request in its slot: a fresh sequence id at
+        position 0, its known tokens, and zeroed recurrent state."""
+        st = self.sched
+        seq_ids = np.asarray(self.state["seq_ids"]).copy()
+        active = np.asarray(self.state["active"]).copy()
+        aborted = np.asarray(self.state["aborted"]).copy()
+        tokens = np.asarray(self.tokens).copy()
+        self._reset_recurrent_state([s for s, _ in admissions])
+        for slot, req in admissions:
+            known = req.known_tokens()
+            self.lane_known[slot] = known
+            self.lane_stop[slot] = st.stop_of(req)
+            seq_ids[slot] = self.next_seq_id
+            self.next_seq_id += 1
+            self.pos[slot] = 0
+            active[slot] = True
+            aborted[slot] = False
+            tokens[slot, 0] = known[0]
+            # fresh admissions start at pos 0 with no pages, so the
+            # invalidated (-1) block-table rows ARE the correct cache;
+            # an admission carrying prefilled pages would rebuild its
+            # rows from the wait-free lookup
+            # (PageTable.rebuild_block_table)
+        self.state["seq_ids"] = jnp.asarray(seq_ids)
+        self.state["active"] = jnp.asarray(active)
+        self.state["aborted"] = jnp.asarray(aborted)
+        self.state["pos"] = jnp.asarray(self.pos)
+        self.tokens = jnp.asarray(tokens)
 
     def _reset_recurrent_state(self, slots):
         """Zero the admitted lanes' PER-LANE recurrent state.  Paged KV
@@ -325,42 +345,64 @@ class ContinuousBatcher:
         return d
 
     def step_round(self):
-        """One scheduled megastep round (K tokens per occupied lane)."""
-        if self.auto_refill:
-            self._refill()
-        with PT.probe_stats_scope() as ps:
-            forced, fmask = self._forcing()
-            p0 = self.pos.copy()
-            toks, self.state = self.mega_fn(
-                self.params, self.state, self.tokens,
-                jnp.asarray(self.lane_stop), jnp.asarray(forced),
-                jnp.asarray(fmask))
-            self.tokens = toks[:, -1:]       # pending feed (refused token
-            self.pos = np.asarray(self.state["pos"]).copy()  # for aborts)
-            self.sched.advance(self.K)       # 1 host sync per K tokens
-            self._absorb(np.asarray(toks), p0, self.pos)
-            self._emit_decode(p0, self.pos)
-            if self.verify and "table" in self.state:
-                self._check_block_table()
-            aborted = self.state.get("aborted")
-            n_ab = (0 if aborted is None
-                    else int(np.asarray(aborted).sum()))
-            if n_ab:
-                # REACTIVE safety net (forecaster off / capped / wrong):
-                # grow the pool, re-hash, move the KV pages, rebuild the
-                # block-table cache, clear the flags; the refused suffix is
-                # re-issued by the next megastep at the frozen positions
-                n_pages = self.state["pools"].k.shape[1]
-                self.state = EG.rebuild_page_table(self.state,
-                                                   n_pages=n_pages * 2,
-                                                   strategy=self.strategy)
-                self.sched.note_aborts(n_ab, grew_to=n_pages * 2)
-                self._emit("rebuild", reason="reactive",
-                           n_pages=n_pages * 2)
-            pool = EG.decode_headroom(self.state, strategy=self.strategy)
-            plan = self.sched.plan_round(self.pos, pool)
-            self._apply_plan(plan)
-            probed = ps["keys_probed"]
+        """One scheduled megastep round (K tokens per occupied lane).  Each
+        phase is a host span (``obs.span``) on the profiler's clock."""
+        with OBS.span("serve.round"):
+            if self.auto_refill:
+                self._refill()
+            with PT.probe_stats_scope() as ps:
+                with OBS.span("serve.forcing"):
+                    forced, fmask = self._forcing()
+                p0 = self.pos.copy()
+                with OBS.span("serve.dispatch"):
+                    toks, self.state = self.mega_fn(
+                        self.params, self.state, self.tokens,
+                        jnp.asarray(self.lane_stop), jnp.asarray(forced),
+                        jnp.asarray(fmask))
+                    # pending feed (the refused token for aborts)
+                    self.tokens = toks[:, -1:]
+                with OBS.span("serve.wait"):
+                    # 1 host sync per K tokens: the megastep's outputs
+                    self.pos = np.asarray(self.state["pos"]).copy()
+                    toks = np.asarray(toks)
+                    aborted = self.state.get("aborted")
+                    n_ab = (0 if aborted is None
+                            else int(np.asarray(aborted).sum()))
+                self.sched.advance(self.K)
+                with OBS.span("serve.absorb"):
+                    self._absorb(toks, p0, self.pos)
+                    self._emit_decode(p0, self.pos)
+                if self.verify and "table" in self.state:
+                    self._check_block_table()
+                if n_ab:
+                    # REACTIVE safety net (forecaster off / capped /
+                    # wrong): grow the pool, re-hash, move the KV pages,
+                    # rebuild the block-table cache, clear the flags; the
+                    # refused suffix is re-issued by the next megastep at
+                    # the frozen positions
+                    n_pages = self.state["pools"].k.shape[1]
+                    with OBS.span("serve.rebuild"):
+                        self.state = EG.rebuild_page_table(
+                            self.state, n_pages=n_pages * 2,
+                            strategy=self.strategy)
+                    self.sched.note_aborts(n_ab, grew_to=n_pages * 2)
+                    self._emit("rebuild", reason="reactive",
+                               n_pages=n_pages * 2)
+                with OBS.span("serve.headroom"):
+                    pool = EG.decode_headroom(self.state,
+                                              strategy=self.strategy)
+                plan = self.sched.plan_round(self.pos, pool)
+                with OBS.span("serve.apply_plan"):
+                    self._apply_plan(plan)
+                probed = ps["keys_probed"]
+            with OBS.span("serve.telemetry"):
+                self._telemetry(pool, probed)
+            self.sched.end_round(keys_probed=probed)
+            return plan
+
+    def _telemetry(self, pool, probed: int):
+        """The round's counters and gauges into the registry, and its
+        ``round`` event into the tracer."""
         self.metrics.inc("keys_probed", probed)
         ctr = self._read_counters()
         if pool is not None:
@@ -383,8 +425,20 @@ class ContinuousBatcher:
                     "migrated": 0, "migration_left": 0}
             self._emit("round", counters=ctr, health=health,
                        keys_probed=probed)
-        self.sched.end_round(keys_probed=probed)
-        return plan
+
+    def megastep_scopes(self) -> dict:
+        """HLO instruction name -> named scope (``obs.SCOPES``) of the
+        megastep compiled for this batcher's shapes: the names a device
+        trace gives the megastep's operations.  The first call compiles the
+        megastep once more (``obs.fresh_hlo_text``)."""
+        if self._scopes is None:
+            B, K = self.B, self.K
+            args = (self.params, self.state, self.tokens,
+                    jnp.asarray(self.lane_stop),
+                    jnp.zeros((B, K), jnp.int32), jnp.zeros((B, K), bool))
+            self._scopes = OBS.scope_map(OBS.fresh_hlo_text(
+                self._megastep, args, donate_argnums=(1,)))
+        return self._scopes
 
     def decode_round(self, steps: int):
         """Drive ~``steps`` decode steps (ceil(steps / K) rounds)."""

@@ -39,7 +39,7 @@ class Counters(NamedTuple):
     rates are then exact even though the device only ever accumulates.
     """
 
-    probe_steps: jnp.ndarray          # hash-table probe steps (alloc path)
+    probe_steps: jnp.ndarray          # 2 per page crossing, not chain steps
     pages_allocated: jnp.ndarray      # page-boundary inserts that landed
     pages_freed: jnp.ndarray          # pages deleted on sequence free
     tombstones_created: jnp.ndarray   # deletes that left a TOMBSTONE
@@ -81,9 +81,10 @@ def update_token_counters(counters: Counters, *, act, aborts, positions,
     Called at the end of the serve step / token body with the pre- and
     post-alloc table (when the family is paged).  Derivations, not taps:
     ``need_new`` is recomputed from positions (a lane allocates exactly at
-    page boundaries), probe work mirrors ``alloc_step_incremental``'s
-    2*need_new host-side note, and tombstone reclamation is the
-    ``num_tombs`` drop across the insert (inserts only ever reclaim;
+    page boundaries); ``probe_steps`` adds 2 per crossing, mirroring
+    ``alloc_step_incremental``'s 2*need_new host-side note, so it counts
+    crossings, not the steps of the probe chains; tombstone reclamation is
+    the ``num_tombs`` drop across the insert (inserts only ever reclaim;
     deletes only ever create — so the sign splits the two counts).
     """
     act_i = act.astype(jnp.int32)

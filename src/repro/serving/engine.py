@@ -465,42 +465,48 @@ def _paged_attn_chip(cfg, x, ap, pools, scales, layer, lp_tree,
     npr = pools.k.shape[1]
     chip = _chip_idx(axes_names, mesh) if axes_names else jnp.int32(0)
 
-    q, k, v = L.attn_qkv_decode(ap, x[:, 0])
-    if axes_names and q_sharded:
-        q = jax.lax.all_gather(q, "model", axis=1, tiled=True)
-    if axes_names and kv_sharded:
-        k = jax.lax.all_gather(k, "model", axis=1, tiled=True)
-        v = jax.lax.all_gather(v, "model", axis=1, tiled=True)
-    q = _rope_single(cfg, q, positions, mrope)
-    k = _rope_single(cfg, k, positions, mrope)
+    with jax.named_scope("attn_proj"):
+        q, k, v = L.attn_qkv_decode(ap, x[:, 0])
+        if axes_names and q_sharded:
+            q = jax.lax.all_gather(q, "model", axis=1, tiled=True)
+        if axes_names and kv_sharded:
+            k = jax.lax.all_gather(k, "model", axis=1, tiled=True)
+            v = jax.lax.all_gather(v, "model", axis=1, tiled=True)
+        q = _rope_single(cfg, q, positions, mrope)
+        k = _rope_single(cfg, k, positions, mrope)
 
-    pools, scales = paged.write_token_kv(
-        pools, scales, k, v, write_slot, positions, chip, npr, page_size,
-        layer)
+    with jax.named_scope("kv_write"):
+        pools, scales = paged.write_token_kv(
+            pools, scales, k, v, write_slot, positions, chip, npr,
+            page_size, layer)
 
     n_kv, G = cfg.n_kv, cfg.n_q // cfg.n_kv
-    if fused:
-        # one Pallas dispatch: in-kernel block-table walk + double-buffered
-        # page DMA + attention partials (kernels/fused_decode)
-        local_bt = _local_block_table(bt, chip, npr)
-        o, m, l = fused_decode_kernel(q, pools.k, pools.v, local_bt,
-                                      positions, layer=layer, scales=scales,
-                                      partials=True, interpret=interpret)
-    else:
-        lp = paged.LocalPages(*(t[0] for t in lp_tree))
-        qg = q.reshape(B, n_kv, G, cfg.hd)
-        o, m, l = paged.attend_local(qg, pools, scales, layer, lp,
-                                     positions, page_size)
-    out = paged.merge_global(o, m, l, axes_names)    # [B,kv,G,hd] f32
-    out = out.reshape(B, cfg.n_q, cfg.hd).astype(x.dtype)
+    with jax.named_scope("attend"):
+        if fused:
+            # one Pallas dispatch: in-kernel block-table walk +
+            # double-buffered page DMA + attention partials
+            # (kernels/fused_decode)
+            local_bt = _local_block_table(bt, chip, npr)
+            o, m, l = fused_decode_kernel(q, pools.k, pools.v, local_bt,
+                                          positions, layer=layer,
+                                          scales=scales, partials=True,
+                                          interpret=interpret)
+        else:
+            lp = paged.LocalPages(*(t[0] for t in lp_tree))
+            qg = q.reshape(B, n_kv, G, cfg.hd)
+            o, m, l = paged.attend_local(qg, pools, scales, layer, lp,
+                                         positions, page_size)
+        out = paged.merge_global(o, m, l, axes_names)  # [B,kv,G,hd] f32
+        out = out.reshape(B, cfg.n_q, cfg.hd).astype(x.dtype)
 
-    if axes_names and q_sharded:
-        hl = cfg.n_q // mesh.shape["model"]
-        my = jax.lax.dynamic_slice_in_dim(
-            out, jax.lax.axis_index("model") * hl, hl, axis=1)
-        y = jax.lax.psum(L.attn_out_decode(ap, my), "model")
-    else:
-        y = L.attn_out_decode(ap, out)
+    with jax.named_scope("attn_proj"):
+        if axes_names and q_sharded:
+            hl = cfg.n_q // mesh.shape["model"]
+            my = jax.lax.dynamic_slice_in_dim(
+                out, jax.lax.axis_index("model") * hl, hl, axis=1)
+            y = jax.lax.psum(L.attn_out_decode(ap, my), "model")
+        else:
+            y = L.attn_out_decode(ap, out)
     return y.astype(x.dtype)[:, None], pools, scales
 
 
@@ -591,31 +597,38 @@ def _ring_attn(cfg, x, ap, ring_k_l, ring_v_l, ring_pos, positions):
     """x [B,1,d]; ring [B,W,kv,hd]; ring_pos [B,W] absolute positions."""
     B = x.shape[0]
     W = ring_k_l.shape[1]
-    q, k, v = L.attn_qkv_decode(ap, x[:, 0])
-    q = _rope_single(cfg, q, positions)
-    k = _rope_single(cfg, k, positions)
+    with jax.named_scope("attn_proj"):
+        q, k, v = L.attn_qkv_decode(ap, x[:, 0])
+        q = _rope_single(cfg, q, positions)
+        k = _rope_single(cfg, k, positions)
     slot = positions % W
-    ring_k_l = ring_k_l.at[jnp.arange(B), slot].set(k.astype(ring_k_l.dtype))
-    ring_v_l = ring_v_l.at[jnp.arange(B), slot].set(v.astype(ring_v_l.dtype))
+    with jax.named_scope("kv_write"):
+        ring_k_l = ring_k_l.at[jnp.arange(B), slot].set(
+            k.astype(ring_k_l.dtype))
+        ring_v_l = ring_v_l.at[jnp.arange(B), slot].set(
+            v.astype(ring_v_l.dtype))
 
     n_kv, G = cfg.n_kv, cfg.n_q // cfg.n_kv
-    qg = q.reshape(B, n_kv, G, cfg.hd)
-    s = jnp.einsum("bkgd,bwkd->bkgw", qg.astype(jnp.float32),
-                   ring_k_l.astype(jnp.float32)) / math.sqrt(cfg.hd)
-    ok = (ring_pos >= 0) & (ring_pos <= positions[:, None]) & \
-        (ring_pos > positions[:, None] - W)
-    ok = ok.at[jnp.arange(B), slot].set(True)
-    s = jnp.where(ok[:, None, None, :], s, paged.NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgw,bwkd->bkgd", p, ring_v_l.astype(jnp.float32))
-    o = o.reshape(B, cfg.n_q, cfg.hd).astype(x.dtype)
-    return (L.attn_out_decode(ap, o).astype(x.dtype)[:, None], ring_k_l,
-            ring_v_l)
+    with jax.named_scope("attend"):
+        qg = q.reshape(B, n_kv, G, cfg.hd)
+        s = jnp.einsum("bkgd,bwkd->bkgw", qg.astype(jnp.float32),
+                       ring_k_l.astype(jnp.float32)) / math.sqrt(cfg.hd)
+        ok = (ring_pos >= 0) & (ring_pos <= positions[:, None]) & \
+            (ring_pos > positions[:, None] - W)
+        ok = ok.at[jnp.arange(B), slot].set(True)
+        s = jnp.where(ok[:, None, None, :], s, paged.NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bkgw,bwkd->bkgd", p, ring_v_l.astype(jnp.float32))
+        o = o.reshape(B, cfg.n_q, cfg.hd).astype(x.dtype)
+    with jax.named_scope("attn_proj"):
+        y = L.attn_out_decode(ap, o).astype(x.dtype)[:, None]
+    return y, ring_k_l, ring_v_l
 
 
 # ---------------------------------------------------------------------------
 # Cross attention at decode (encdec): dense precomputed memory K/V.
 
+@jax.named_scope("attend")
 def _cross_attn_decode(cfg, x, cp, ck, cv):
     """x [B,1,d]; ck/cv [B,S_src,kv,hd]."""
     B = x.shape[0]
@@ -763,28 +776,35 @@ def _paged_attn_shard(cfg, x, ap, pools, scales, layer, lp, write_slot,
     (disjoint) slice of that head's query group — the psum over ``model``
     still sums distinct q-head contributions exactly once."""
     B = x.shape[0]
-    q, k, v = _qkv_decode_shard(ap, x[:, 0], kv_rep)
-    q = _rope_single(cfg, q, positions, mrope)
-    k = _rope_single(cfg, k, positions, mrope)
-    pools, scales = paged.write_token_kv(pools, scales, k, v, write_slot,
-                                         positions, chip_pd, npr, page_size,
-                                         layer)
+    with jax.named_scope("attn_proj"):
+        q, k, v = _qkv_decode_shard(ap, x[:, 0], kv_rep)
+        q = _rope_single(cfg, q, positions, mrope)
+        k = _rope_single(cfg, k, positions, mrope)
+    with jax.named_scope("kv_write"):
+        pools, scales = paged.write_token_kv(pools, scales, k, v,
+                                             write_slot, positions, chip_pd,
+                                             npr, page_size, layer)
     kv_l = k.shape[1]                              # n_kv·rep / tp
     G_l = q.shape[1] // kv_l                       # local group size
-    if fused_bt is not None:
-        # one Pallas dispatch per layer: in-kernel walk of the chip-local
-        # raw block table + double-buffered page DMA (kernels/fused_decode);
-        # same (o, m, l) partials contract as paged.attend_local
-        o, m, l = fused_decode_kernel(q, pools.k, pools.v, fused_bt,
-                                      positions, layer=layer, scales=scales,
-                                      partials=True, interpret=interpret)
-    else:
-        qg = q.reshape(B, kv_l, G_l, cfg.hd)       # grouping is head-local
-        o, m, l = paged.attend_local(qg, pools, scales, layer, lp,
-                                     positions, page_size)
-    out = paged.merge_global(o, m, l, pd_axes)     # heads never cross chips
-    out = out.reshape(B, kv_l * G_l, cfg.hd).astype(x.dtype)
-    y = jax.lax.psum(L.attn_out_decode(ap, out), "model").astype(x.dtype)
+    with jax.named_scope("attend"):
+        if fused_bt is not None:
+            # one Pallas dispatch per layer: in-kernel walk of the
+            # chip-local raw block table + double-buffered page DMA
+            # (kernels/fused_decode); same (o, m, l) partials contract as
+            # paged.attend_local
+            o, m, l = fused_decode_kernel(q, pools.k, pools.v, fused_bt,
+                                          positions, layer=layer,
+                                          scales=scales, partials=True,
+                                          interpret=interpret)
+        else:
+            qg = q.reshape(B, kv_l, G_l, cfg.hd)   # grouping is head-local
+            o, m, l = paged.attend_local(qg, pools, scales, layer, lp,
+                                         positions, page_size)
+        out = paged.merge_global(o, m, l, pd_axes)  # heads never cross chips
+        out = out.reshape(B, kv_l * G_l, cfg.hd).astype(x.dtype)
+    with jax.named_scope("attn_proj"):
+        y = jax.lax.psum(L.attn_out_decode(ap, out),
+                         "model").astype(x.dtype)
     return y[:, None], pools, scales
 
 
@@ -797,26 +817,32 @@ def _ring_attn_shard(cfg, x, ap, ring_k_l, ring_v_l, ring_pos, positions,
     row-parallel out + one psum.  x [B,1,d]; ring_*_l [B,W,kv_l,hd]."""
     B = x.shape[0]
     W = ring_k_l.shape[1]
-    q, k, v = _qkv_decode_shard(ap, x[:, 0], kv_rep)
-    q = _rope_single(cfg, q, positions)
-    k = _rope_single(cfg, k, positions)
+    with jax.named_scope("attn_proj"):
+        q, k, v = _qkv_decode_shard(ap, x[:, 0], kv_rep)
+        q = _rope_single(cfg, q, positions)
+        k = _rope_single(cfg, k, positions)
     slot = positions % W
-    ring_k_l = ring_k_l.at[jnp.arange(B), slot].set(k.astype(ring_k_l.dtype))
-    ring_v_l = ring_v_l.at[jnp.arange(B), slot].set(v.astype(ring_v_l.dtype))
+    with jax.named_scope("kv_write"):
+        ring_k_l = ring_k_l.at[jnp.arange(B), slot].set(
+            k.astype(ring_k_l.dtype))
+        ring_v_l = ring_v_l.at[jnp.arange(B), slot].set(
+            v.astype(ring_v_l.dtype))
 
     kv_l = k.shape[1]
     G_l = q.shape[1] // kv_l
-    qg = q.reshape(B, kv_l, G_l, cfg.hd)
-    s = jnp.einsum("bkgd,bwkd->bkgw", qg.astype(jnp.float32),
-                   ring_k_l.astype(jnp.float32)) / math.sqrt(cfg.hd)
-    ok = (ring_pos >= 0) & (ring_pos <= positions[:, None]) & \
-        (ring_pos > positions[:, None] - W)
-    ok = ok.at[jnp.arange(B), slot].set(True)
-    s = jnp.where(ok[:, None, None, :], s, paged.NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgw,bwkd->bkgd", p, ring_v_l.astype(jnp.float32))
-    o = o.reshape(B, kv_l * G_l, cfg.hd).astype(x.dtype)
-    y = jax.lax.psum(L.attn_out_decode(ap, o), "model").astype(x.dtype)
+    with jax.named_scope("attend"):
+        qg = q.reshape(B, kv_l, G_l, cfg.hd)
+        s = jnp.einsum("bkgd,bwkd->bkgw", qg.astype(jnp.float32),
+                       ring_k_l.astype(jnp.float32)) / math.sqrt(cfg.hd)
+        ok = (ring_pos >= 0) & (ring_pos <= positions[:, None]) & \
+            (ring_pos > positions[:, None] - W)
+        ok = ok.at[jnp.arange(B), slot].set(True)
+        s = jnp.where(ok[:, None, None, :], s, paged.NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bkgw,bwkd->bkgd", p, ring_v_l.astype(jnp.float32))
+        o = o.reshape(B, kv_l * G_l, cfg.hd).astype(x.dtype)
+    with jax.named_scope("attn_proj"):
+        y = jax.lax.psum(L.attn_out_decode(ap, o), "model").astype(x.dtype)
     return y[:, None], ring_k_l, ring_v_l
 
 
@@ -864,24 +890,28 @@ def _manual_decode_parts(cfg, *, S_max: int, rules,
         return param_specs, state_specs
 
     def token_body(params, state, tokens, positions, mrope, *, npr, cap):
-        x = nn.embed_lookup(params["embed"], tokens)      # replicated
+        with jax.named_scope("embed"):
+            x = nn.embed_lookup(params["embed"], tokens)  # replicated
         new_state = dict(state)
         chip_pd = _chip_idx(pd_axes, mesh)
         act = state["active"] & ~state["aborted"]
         # once per token, identical on every chip: incremental allocation
         # (only crossings probe) + the cached block-table read; the paper's
         # wait-free lookup stays authoritative for admission/rebuild
-        (table, write_slot, aborts), bt = _pt(cfg).alloc_step_incremental(
-            state["table"], state["seq_ids"], positions,
-            state["block_table"], page_size=page_size, active=act)
-        if use_fused:
-            # the fused kernel walks the raw block table in-kernel: no
-            # materialized slots view, no per-chip compaction pass
-            lp, fused_bt = None, _local_block_table(bt, chip_pd, npr)
-        else:
-            slots = PT.PageTable.block_table_slots(
-                bt, positions, page_size=page_size)
-            lp, fused_bt = paged.compact_local(slots, chip_pd, npr, cap), None
+        with jax.named_scope("allocator"):
+            (table, write_slot, aborts), bt = \
+                _pt(cfg).alloc_step_incremental(
+                    state["table"], state["seq_ids"], positions,
+                    state["block_table"], page_size=page_size, active=act)
+            if use_fused:
+                # the fused kernel walks the raw block table in-kernel: no
+                # materialized slots view, no per-chip compaction pass
+                lp, fused_bt = None, _local_block_table(bt, chip_pd, npr)
+            else:
+                slots = PT.PageTable.block_table_slots(
+                    bt, positions, page_size=page_size)
+                lp = paged.compact_local(slots, chip_pd, npr, cap)
+                fused_bt = None
         new_state["table"] = table
         new_state["block_table"] = bt
         new_state["aborted"] = state["aborted"] | aborts
@@ -916,10 +946,11 @@ def _manual_decode_parts(cfg, *, S_max: int, rules,
                     scales, li, mrope=mrope)
                 x = x + h
                 xn = nn.rmsnorm(lpar["ln2"], x)
-                if cfg.family == "moe":
-                    y = MOE.moe_decode_local(lpar["moe"], xn, cfg)
-                else:
-                    y = TP.mlp_decode_manual(lpar["mlp"], xn)
+                with jax.named_scope("mlp"):
+                    if cfg.family == "moe":
+                        y = MOE.moe_decode_local(lpar["moe"], xn, cfg)
+                    else:
+                        y = TP.mlp_decode_manual(lpar["mlp"], xn)
                 return (x + y, pools, scales), None
 
             (x_out, pools, scales), _ = jax.lax.scan(
@@ -927,9 +958,10 @@ def _manual_decode_parts(cfg, *, S_max: int, rules,
                 (params["layers"], jnp.arange(cfg.num_layers)),
                 unroll=cfg.scan_unroll)
             _set_pools(new_state, pools, scales)
-        x_out = nn.rmsnorm(params["final_norm"], x_out)
-        logits = TP.logits_decode_manual(cfg, params, x_out,
-                                         vocab_sharded=vocab_sharded)
+        with jax.named_scope("lm_head"):
+            x_out = nn.rmsnorm(params["final_norm"], x_out)
+            logits = TP.logits_decode_manual(cfg, params, x_out,
+                                             vocab_sharded=vocab_sharded)
         new_state["pos"] = jnp.where(act & ~aborts, positions + 1,
                                      positions)
         return logits[:, 0].astype(jnp.float32), new_state
@@ -998,15 +1030,17 @@ def _mega_scan(cfg, K: int, token_step, state, tokens, stop_len,
                                   (3, B, 1)).astype(jnp.int32)
                  if cfg.family == "vlm" else None)
         logits, st2 = token_step(st, tok, pos, mrope)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        if xs is not None:
-            f_tok, f_msk = xs
-            nxt = jnp.where(f_msk[:, None], f_tok[:, None], nxt)
-        # aborted lanes keep their refused token pending for the re-issue
-        tok2 = jnp.where(st2["aborted"][:, None], tok, nxt)
-        if stop_len is not None:
-            st2 = dict(st2)
-            st2["active"] = st2["active"] & (st2["pos"] < stop_len)
+        with jax.named_scope("sampling"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+            if xs is not None:
+                f_tok, f_msk = xs
+                nxt = jnp.where(f_msk[:, None], f_tok[:, None], nxt)
+            # aborted lanes keep their refused token pending for the
+            # re-issue
+            tok2 = jnp.where(st2["aborted"][:, None], tok, nxt)
+            if stop_len is not None:
+                st2 = dict(st2)
+                st2["active"] = st2["active"] & (st2["pos"] < stop_len)
         return (st2, tok2), tok2[:, 0]
 
     xs = None
@@ -1079,15 +1113,18 @@ def _gemma_layers_shard(cfg, params, state, new_state, x, attn, positions,
                 cfg, nn.rmsnorm(sub["ln1"], x), sub["attn"], rks[i],
                 rvs[i], state["ring_pos"], positions, kv_rep)
             x = x + h
-            x = x + TP.mlp_decode_manual(sub["mlp"],
-                                         nn.rmsnorm(sub["ln2"], x))
+            xn = nn.rmsnorm(sub["ln2"], x)
+            with jax.named_scope("mlp"):
+                x = x + TP.mlp_decode_manual(sub["mlp"], xn)
             new_rk.append(rk2)
             new_rv.append(rv2)
         sub = jax.tree.map(lambda t: t[pat], grp)
         h, pools, scales = attn(nn.rmsnorm(sub["ln1"], x), sub["attn"],
                                 pools, scales, gi, mrope=None)
         x = x + h
-        x = x + TP.mlp_decode_manual(sub["mlp"], nn.rmsnorm(sub["ln2"], x))
+        xn = nn.rmsnorm(sub["ln2"], x)
+        with jax.named_scope("mlp"):
+            x = x + TP.mlp_decode_manual(sub["mlp"], xn)
         return (x, pools, scales), (jnp.stack(new_rk), jnp.stack(new_rv))
 
     (x, pools, scales), (rk, rv) = jax.lax.scan(
@@ -1117,19 +1154,23 @@ def _hybrid_layers_shard(cfg, params, state, new_state, x, attn,
     pools, scales = state["pools"], state.get("pool_scales")
     new_ssm_chunks = []
     for g in range(n_inv):
-        x, s2 = HY.mamba_decode_chunk(cfg, params["layers"], state["ssm"],
-                                      x, g * every, (g + 1) * every,
-                                      tp_axis=ssm_axis)
+        with jax.named_scope("ssm"):
+            x, s2 = HY.mamba_decode_chunk(cfg, params["layers"],
+                                          state["ssm"], x, g * every,
+                                          (g + 1) * every, tp_axis=ssm_axis)
         new_ssm_chunks.append(s2)
         h, pools, scales = attn(nn.rmsnorm(sp["ln1"], x), sp["attn"],
                                 pools, scales, g, mrope=None)
         x = x + h
-        x = x + TP.mlp_decode_manual(sp["mlp"], nn.rmsnorm(sp["ln2"], x))
+        xn = nn.rmsnorm(sp["ln2"], x)
+        with jax.named_scope("mlp"):
+            x = x + TP.mlp_decode_manual(sp["mlp"], xn)
     rem = cfg.num_layers - n_inv * every
     if rem:
-        x, s2 = HY.mamba_decode_chunk(cfg, params["layers"], state["ssm"],
-                                      x, n_inv * every, cfg.num_layers,
-                                      tp_axis=ssm_axis)
+        with jax.named_scope("ssm"):
+            x, s2 = HY.mamba_decode_chunk(cfg, params["layers"],
+                                          state["ssm"], x, n_inv * every,
+                                          cfg.num_layers, tp_axis=ssm_axis)
         new_ssm_chunks.append(s2)
     # new_state["aborted"] already includes this step's aborts: a refused
     # lane's recurrence must not advance (its token is re-issued later)
@@ -1141,6 +1182,7 @@ def _hybrid_layers_shard(cfg, params, state, new_state, x, attn,
     return x
 
 
+@jax.named_scope("allocator")
 def _page_ops(cfg, state, positions, active, *, S_max, page_size, n_chips,
               rules, fused=False):
     """Once-per-token page-table work: incremental allocation (only the
@@ -1165,6 +1207,7 @@ def _page_ops(cfg, state, positions, active, *, S_max, page_size, n_chips,
     return table, write_slot, aborts, bt, lp_arrays
 
 
+@jax.named_scope("state_freeze")
 def _freeze_lanes(new_tree, old_tree, act):
     """Per-lane state freeze for refused/inactive lanes: leaves are
     [L, B, ...] stacked per-layer state.  A refused token must be
@@ -1184,6 +1227,7 @@ def _set_pools(new_state, pools, scales):
         new_state["pool_scales"] = scales
 
 
+@jax.named_scope("mlp")
 def _mlp_or_moe(cfg, p, x):
     if cfg.family == "moe":
         y, _ = MOE.moe_apply(p["moe"], x, cfg)
@@ -1194,7 +1238,8 @@ def _mlp_or_moe(cfg, p, x):
 def _serve_step_impl(cfg, params, state, tokens, positions, mrope,
                      *, rules, S_max, page_size, n_chips):
     B = tokens.shape[0]
-    x = nn.embed_lookup(params["embed"], tokens)      # [B,1,d]
+    with jax.named_scope("embed"):
+        x = nn.embed_lookup(params["embed"], tokens)  # [B,1,d]
     new_state = dict(state)
     act = state["active"] & ~state["aborted"]
     aborts = jnp.zeros((B,), bool)
@@ -1243,8 +1288,10 @@ def _serve_step_impl(cfg, params, state, tokens, positions, mrope,
     elif cfg.family == "ssm":
         def body(x, xs):
             lp_params, st = xs
-            h, st2 = ssm.mamba_decode_step(
-                lp_params["mamba"], nn.rmsnorm(lp_params["ln"], x), cfg, st)
+            xn = nn.rmsnorm(lp_params["ln"], x)
+            with jax.named_scope("ssm"):
+                h, st2 = ssm.mamba_decode_step(lp_params["mamba"], xn, cfg,
+                                               st)
             return x + h, st2
 
         x, ssm2 = jax.lax.scan(body, x, (params["layers"], state["ssm"]),
@@ -1264,21 +1311,25 @@ def _serve_step_impl(cfg, params, state, tokens, positions, mrope,
         pools, scales = state["pools"], state.get("pool_scales")
         sp = params["shared"]
         for g in range(n_inv):
-            x, s2 = HY.mamba_decode_chunk(cfg, params["layers"],
-                                          state["ssm"], x,
-                                          g * every, (g + 1) * every)
+            with jax.named_scope("ssm"):
+                x, s2 = HY.mamba_decode_chunk(cfg, params["layers"],
+                                              state["ssm"], x,
+                                              g * every, (g + 1) * every)
             new_ssm_chunks.append(s2)
             h, pools, scales = paged_attn_op(
                 cfg, rules, nn.rmsnorm(sp["ln1"], x), sp["attn"], pools,
                 scales, g, lp, write_slot, positions, None, page_size,
                 bt=bt if fused else None, fused=fused, interpret=interp)
             x = x + h
-            x = x + L.mlp_apply(sp["mlp"], nn.rmsnorm(sp["ln2"], x))
+            xn = nn.rmsnorm(sp["ln2"], x)
+            with jax.named_scope("mlp"):
+                x = x + L.mlp_apply(sp["mlp"], xn)
         rem = cfg.num_layers - n_inv * every
         if rem:
-            x, s2 = HY.mamba_decode_chunk(cfg, params["layers"],
-                                          state["ssm"], x,
-                                          n_inv * every, cfg.num_layers)
+            with jax.named_scope("ssm"):
+                x, s2 = HY.mamba_decode_chunk(cfg, params["layers"],
+                                              state["ssm"], x,
+                                              n_inv * every, cfg.num_layers)
             new_ssm_chunks.append(s2)
         # a lane refused THIS step (abort) re-issues its token after the
         # rebuild — its recurrent state must not advance either
@@ -1304,8 +1355,9 @@ def _serve_step_impl(cfg, params, state, tokens, positions, mrope,
             x = x + h
             x = x + _cross_attn_decode(cfg, nn.rmsnorm(lp_params["ln_cross"], x),
                                        lp_params["cross"], ck, cv)
-            x = x + L.mlp_apply(lp_params["mlp"],
-                                nn.rmsnorm(lp_params["ln2"], x))
+            xn = nn.rmsnorm(lp_params["ln2"], x)
+            with jax.named_scope("mlp"):
+                x = x + L.mlp_apply(lp_params["mlp"], xn)
             return (x, pools, scales), None
 
         (x, pools, scales), _ = jax.lax.scan(
@@ -1317,11 +1369,12 @@ def _serve_step_impl(cfg, params, state, tokens, positions, mrope,
     else:
         raise ValueError(cfg.family)
 
-    x = nn.rmsnorm(params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = nn.embed_logits(params["embed"], x)
-    else:
-        logits = nn.dense(params["lm_head"], x)
+    with jax.named_scope("lm_head"):
+        x = nn.rmsnorm(params["final_norm"], x)
+        if cfg.tie_embeddings:
+            logits = nn.embed_logits(params["embed"], x)
+        else:
+            logits = nn.dense(params["lm_head"], x)
     # inactive lanes stay frozen; aborted lanes refuse the token (pos not
     # advanced, no KV written — the caller must evict or rebuild)
     new_state["aborted"] = state["aborted"] | aborts
@@ -1380,7 +1433,9 @@ def _gemma_layers(cfg, params, state, x, lp, write_slot, positions, rules,
                                      sub["attn"], rks[i], rvs[i],
                                      state["ring_pos"], positions)
             x = x + h
-            x = x + L.mlp_apply(sub["mlp"], nn.rmsnorm(sub["ln2"], x))
+            xn = nn.rmsnorm(sub["ln2"], x)
+            with jax.named_scope("mlp"):
+                x = x + L.mlp_apply(sub["mlp"], xn)
             new_rk.append(rk2)
             new_rv.append(rv2)
         sub = jax.tree.map(lambda t: t[pat], grp)
@@ -1389,7 +1444,9 @@ def _gemma_layers(cfg, params, state, x, lp, write_slot, positions, rules,
             scales, gi, lp, write_slot, positions, None, page_size,
             bt=bt if fused else None, fused=fused, interpret=interpret)
         x = x + h
-        x = x + L.mlp_apply(sub["mlp"], nn.rmsnorm(sub["ln2"], x))
+        xn = nn.rmsnorm(sub["ln2"], x)
+        with jax.named_scope("mlp"):
+            x = x + L.mlp_apply(sub["mlp"], xn)
         return (x, pools, scales), (jnp.stack(new_rk), jnp.stack(new_rv))
 
     (x, pools, scales), (rk, rv) = jax.lax.scan(

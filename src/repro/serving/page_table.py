@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs as OBS
 from repro.core import batched as BT
 from repro.core import encoding as E
 from repro.core.probe_strategies import get_strategy
@@ -290,9 +291,11 @@ class PageTable:
             (jnp.ones((B, 1), bool) if active is None
              else jnp.asarray(active, bool)[:, None]),
             (B, max_pages)).reshape(-1)
-        table, _ = BT.delete_batch(table, keys, active=act,
-                                   strategy=self.strategy)
-        _note_probes(jnp.sum(act))
+        with OBS.span("pt.delete"):
+            table, _ = BT.delete_batch(table, keys, active=act,
+                                       strategy=self.strategy)
+        with OBS.span("pt.count"):       # a device sync, to count keys
+            _note_probes(jnp.sum(act))
         return table
 
     # -- reads ----------------------------------------------------------

@@ -42,6 +42,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs as OBS
 from repro.serving.sched.forecast import (Forecast, OccupancyForecaster,
                                           pages_held, pages_needed)
 from repro.serving.sched.policy import Policy, get_policy
@@ -246,6 +247,10 @@ class Scheduler:
         post-megastep lane positions; ``pool`` is the engine's
         ``page_table.Headroom`` (None for attention-free families —
         admission is then slot-gated only)."""
+        with OBS.span("sched.plan_round"):
+            return self._plan(positions, pool)
+
+    def _plan(self, positions: Sequence[int], pool) -> Plan:
         pos = np.asarray(positions, np.int64)
         K, ps = self.K, self.page_size
         # probe-strategy headroom: hopscotch reports slack = H because an
