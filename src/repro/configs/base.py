@@ -67,11 +67,13 @@ class ModelConfig:
     # paged-decode per-chip page-capacity factor over the uniform share
     page_capacity_factor: float = 2.0
     # decode attention as ONE fused Pallas dispatch that walks the raw
-    # incremental block table in-kernel with double-buffered page DMA
-    # (kernels/fused_decode) instead of the two-dispatch slots+compact →
-    # attend path.  Gated per path by serving/engine._fused_kernel_reason;
-    # a fallback is always logged + surfaced in dryrun meta, never silent.
-    fused_kernel: bool = False
+    # incremental block table in-kernel and reads only live pages
+    # (kernels/fused_decode) instead of the jnp gather of every capacity
+    # page.  None (the default) follows the platform: the kernel on TPU,
+    # the jnp gather elsewhere; True/False force it (interpret-mode tests).
+    # Gated per path by serving/engine._fused_kernel_reason; a fallback is
+    # always logged + surfaced in dryrun meta, never silent.
+    fused_kernel: Optional[bool] = None
     # page-allocator probe strategy: "linear" (the paper's algorithm),
     # "robinhood" (displacement-ordered claims) or "hopscotch"
     # (neighborhood bitmaps, tombstone-free deletes) — see

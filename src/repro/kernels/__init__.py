@@ -1,3 +1,9 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels: batched probing, paged attention, fused decode."""
+import jax
+
+
+def on_tpu() -> bool:
+    """Whether the program runs on a TPU: the one answer both the kernels'
+    interpret mode (interpreted everywhere else) and the engine's choice of
+    decode-attention path read."""
+    return jax.default_backend() == "tpu"

@@ -22,15 +22,16 @@ never retries, so reading the block-table row at dispatch time is a
 linearizable snapshot — there is no lock a stalled DMA could hold.
 
 Grid: (B,) — the page loop is an in-kernel ``fori_loop`` (the pipeline
-needs manual DMA control, so pages cannot be a grid dimension).  Each DMA
-brings a whole page, every kv head of it ([PS, KH, D]), and a static loop
-walks the heads: Mosaic refuses a one-head slice of the tiled (KH, D) dims
-as a DMA source, and one copy per page moves the same bytes in fewer
-descriptors.  The f32
-online-softmax update replicates ``paged_attention._pa_kernel`` op for op
-(same ``dot_general`` shapes, same masking, same reciprocal-multiply
-finish), so the fused kernel's normalized output is **bitwise identical**
-to the two-dispatch baseline — asserted by tests/test_kernel_fused.py.
+needs manual DMA control, so pages cannot be a grid dimension) whose trip
+count is the lane's own ``pos // PS + 1`` slots, not the table's ``MP``.
+Each DMA brings a whole page, every kv head of it ([PS, KH, D]), and a
+static loop walks the heads: Mosaic refuses a one-head slice of the tiled
+(KH, D) dims as a DMA source, and one copy per page moves the same bytes in
+fewer descriptors.  The f32 online-softmax update replicates
+``paged_attention._pa_kernel`` op for op (same ``dot_general`` shapes, same
+masking, same reciprocal-multiply finish), so the fused kernel's normalized
+output is **bitwise identical** to the two-dispatch baseline — asserted by
+tests/test_kernel_fused.py.
 
 ``partials=True`` skips the normalization and emits the per-chip
 ``(acc, m, l)`` triple consumed by ``serving/paged.merge_global`` — the
@@ -77,11 +78,16 @@ def _fused_kernel(bt_ref, pos_ref, layer_ref,  # scalar prefetch [B,MP],
     b = pl.program_id(0)
     pos = pos_ref[b]
     layer = layer_ref[0]
+    # the walk ends at the page that holds ``pos``: no later slot can hold
+    # a live token, so the loop's trip count follows the lane's length
+    n = jnp.minimum(pos // PS + 1, MP)
 
     def need(p):
         """Page p contributes at least one valid token — the ONLY pages the
-        kernel fetches (the two-dispatch baseline DMAs all MP)."""
-        return (p * PS <= pos) & (bt_ref[b, p] >= 0)
+        kernel fetches (the two-dispatch baseline DMAs all MP).  The table
+        read is clamped so that the prefetch test of the last slot stays in
+        bounds."""
+        return (p * PS <= pos) & (bt_ref[b, jnp.minimum(p, MP - 1)] >= 0)
 
     def copies(pid, slot):
         """The async copies of page ``pid`` (every kv head) into ``slot``."""
@@ -118,7 +124,7 @@ def _fused_kernel(bt_ref, pos_ref, layer_ref,  # scalar prefetch [B,MP],
     def body(p, _):
         slot = jax.lax.rem(p, 2)
 
-        @pl.when((p + 1 < MP) & need(p + 1))
+        @pl.when((p + 1 < n) & need(p + 1))
         def _prefetch_next():
             start(p + 1, 1 - slot)
 
@@ -154,7 +160,7 @@ def _fused_kernel(bt_ref, pos_ref, layer_ref,  # scalar prefetch [B,MP],
 
         return 0
 
-    jax.lax.fori_loop(0, MP, body, 0)
+    jax.lax.fori_loop(0, n, body, 0)
 
     for h in range(KH):
         if partials:

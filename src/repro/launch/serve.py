@@ -115,6 +115,14 @@ class ContinuousBatcher:
         self.metrics.source("probe", lambda: dict(PT.PROBE_STATS))
         OBS.listen_compiles()
         self.metrics.source("jit", OBS.compile_stats)
+        # the decode-attention path the megastep was built with, and the
+        # KV pages the jnp gather reads per attention layer and token step
+        # whatever the lanes hold; the pages read are counted each round
+        # from positions the round fetches anyway (``attention_reads``)
+        self.attn_path = EG.attention_path(cfg, rules)
+        self.gather_pages = EG.gather_pages(cfg, rules, batch, max_len,
+                                            page_size)
+        self.metrics.set_info("attention_path", self.attn_path or "none")
         self._ctr_prev: dict = {}
         # the megastep's scope map, built when first asked for; published
         # so that an in-process trace reader can name its device operations
@@ -354,6 +362,7 @@ class ContinuousBatcher:
                 with OBS.span("serve.forcing"):
                     forced, fmask = self._forcing()
                 p0 = self.pos.copy()
+                seated = np.array([r is not None for r in self.sched.lanes])
                 with OBS.span("serve.dispatch"):
                     toks, self.state = self.mega_fn(
                         self.params, self.state, self.tokens,
@@ -368,6 +377,7 @@ class ContinuousBatcher:
                     aborted = self.state.get("aborted")
                     n_ab = (0 if aborted is None
                             else int(np.asarray(aborted).sum()))
+                p1 = self.pos.copy()
                 self.sched.advance(self.K)
                 with OBS.span("serve.absorb"):
                     self._absorb(toks, p0, self.pos)
@@ -397,6 +407,7 @@ class ContinuousBatcher:
                 probed = ps["keys_probed"]
             with OBS.span("serve.telemetry"):
                 self._telemetry(pool, probed)
+                self._count_attention_reads(p0, p1, seated)
             self.sched.end_round(keys_probed=probed)
             return plan
 
@@ -425,6 +436,32 @@ class ContinuousBatcher:
                     "migrated": 0, "migration_left": 0}
             self._emit("round", counters=ctr, health=health,
                        keys_probed=probed)
+
+    def _count_attention_reads(self, p0, p1, seated):
+        """The round's KV page reads into the registry: ``attn_live_pages``
+        (what the fused kernel reads), ``attn_gather_pages`` (what the jnp
+        gather reads), both per attention layer over the K token steps."""
+        if self.attn_path is None:
+            return
+        self.metrics.inc("attn_token_steps", self.K)
+        self.metrics.inc("attn_live_pages", EG.live_pages_read(
+            p0, p1, seated, self.K, self.page_size))
+        self.metrics.inc("attn_gather_pages", self.K * self.gather_pages)
+
+    def attention_reads(self) -> dict:
+        """The attention path and, per attention layer and token step, the
+        KV pages it read and the pages the jnp gather reads; ``live_share``
+        is their ratio on the kernel, the share of the gather's reads that
+        hold a live token."""
+        m = self.metrics
+        steps = max(m.counter("attn_token_steps"), 1)
+        live = m.counter("attn_live_pages") / steps
+        gather = m.counter("attn_gather_pages") / steps
+        fused = self.attn_path == "fused_decode_kernel"
+        return {"path": self.attn_path or "none",
+                "pages_read_per_step": live if fused else gather,
+                "gather_pages_per_step": gather,
+                "live_share": live / gather if gather else 0.0}
 
     def megastep_scopes(self) -> dict:
         """HLO instruction name -> named scope (``obs.SCOPES``) of the
@@ -608,6 +645,12 @@ def run(cfg, params, args: argparse.Namespace, *, rules=None) -> dict:
                      for k, v in summary.items()))
     print(f"[serve] done — megastep K={srv.K}: host synced once per K "
           "tokens; page slots were reused in place (no compaction)")
+    if srv.attn_path is not None:
+        a = srv.attention_reads()
+        print(f"[serve] attention: {a['path']} read "
+              f"{a['pages_read_per_step']:.1f} KV pages per layer and token "
+              f"step; the jnp gather reads {a['gather_pages_per_step']:.0f} "
+              f"(live share {100 * a['live_share']:.1f}%)")
     if tracer is not None:
         srv.emit_summary()
         tracer.close()

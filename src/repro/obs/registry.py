@@ -46,6 +46,9 @@ class MetricsRegistry:
     def inc(self, name: str, n: Number = 1) -> None:
         self._counters[name] = self._counters.get(name, 0) + n
 
+    def counter(self, name: str) -> Number:
+        return self._counters.get(name, 0)
+
     def set_gauge(self, name: str, v: Number) -> None:
         self._gauges[name] = v
 

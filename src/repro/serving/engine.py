@@ -65,8 +65,10 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import kernels as KN
 from repro.dist import ctx
 from repro.dist import tp as TP
 from repro.dist.compat import shard_map
@@ -117,6 +119,14 @@ def _pd_axes(rules):
     return tuple(a for a in ("pod", "data") if a in rules.mesh.shape)
 
 
+def _n_pd(rules) -> int:
+    """Chips along the page axes of the fused manual decode layout."""
+    n = 1
+    for a in _pd_axes(rules):
+        n *= rules.mesh.shape[a]
+    return n
+
+
 # The two genuinely unsupported families — everything else (dense incl.
 # the gemma3 local-window pattern, moe, vlm, hybrid) takes the fused path.
 _MANUAL_UNSUPPORTED_FAMILY = {
@@ -140,14 +150,24 @@ def _manual_decode_ok(cfg, rules) -> bool:
     return _manual_decode_reason(cfg, rules) is None
 
 
+def _fused_kernel_wanted(cfg) -> bool:
+    """``cfg.fused_kernel`` where set; unset, the platform decides: the
+    kernel on TPU, the jnp gather everywhere else (interpreted Pallas in
+    every CPU serving step would cost more than it tests)."""
+    if cfg.fused_kernel is None:
+        return KN.on_tpu()
+    return bool(cfg.fused_kernel)
+
+
 def _fused_kernel_reason(cfg, rules) -> Optional[str]:
     """Why decode attention does NOT run as the one-dispatch fused
     probe+paged-attention Pallas kernel (kernels/fused_decode) — None when
     it does.  Evaluated for whichever serve path (manual region or gspmd)
-    the step factory actually picks; a non-None reason with
-    ``cfg.fused_kernel=True`` is logged by the factories and recorded in
-    dry-run artifacts (``fused_kernel`` field), never silent."""
-    if not cfg.fused_kernel:
+    the step factory actually picks; a non-None reason where the kernel was
+    wanted (``_fused_kernel_wanted``) is logged by the factories and
+    recorded in dry-run artifacts (``fused_kernel`` field), never
+    silent."""
+    if cfg.fused_kernel is not None and not cfg.fused_kernel:
         return "off (cfg.fused_kernel=False)"
     if cfg.family == "ssm":
         return "attention-free SSM stack: no paged decode attention"
@@ -157,11 +177,54 @@ def _fused_kernel_reason(cfg, rules) -> Optional[str]:
         if TP.decode_kv_rep(cfg, rules.mesh.shape["model"]) != 1:
             return ("kv_rep>1: replicated-KV manual layout keeps the "
                     "two-dispatch per-chip attend path")
+    if not _fused_kernel_wanted(cfg):
+        return (f"off the TPU ({jax.default_backend()} backend): the jnp "
+                "gather attends")
     return None
 
 
 def _fused_kernel_ok(cfg, rules) -> bool:
     return _fused_kernel_reason(cfg, rules) is None
+
+
+def attention_path(cfg, rules=None) -> Optional[str]:
+    """The decode-attention path the serve factories build for ``cfg``:
+    ``"fused_decode_kernel"`` (live pages only, walked in-kernel),
+    ``"jnp_gather"`` (every capacity page, ``paged.attend_local``), or None
+    for a family with no paged attention."""
+    if cfg.family == "ssm":
+        return None
+    return "fused_decode_kernel" if _fused_kernel_ok(cfg, rules) \
+        else "jnp_gather"
+
+
+def gather_pages(cfg, rules, B: int, S_max: int, page_size: int) -> int:
+    """Pages the jnp gather reads per attention layer and token step, over
+    every chip: ``paged.capacity`` on each chip that holds pages (the page
+    axes of the manual region, every chip of the gspmd step), whatever
+    the lanes hold."""
+    if rules is not None and _manual_decode_ok(cfg, rules):
+        n = _n_pd(rules)
+    else:
+        n = _n_chips(rules)
+    maxP = -(-S_max // page_size)
+    return n * paged.capacity(B, maxP, n, factor=cfg.page_capacity_factor)
+
+
+def live_pages_read(p0, p1, seated, K: int, page_size: int) -> int:
+    """KV pages the fused kernel reads in one megastep, per attention layer,
+    summed over its K token steps, from the positions at its start ``p0``
+    and end ``p1`` (int[B]) and the lanes that hold block-table rows
+    (``seated`` bool[B]).  A lane advances one position a step until it
+    stops, so at step k it is at ``p0 + k`` and reads pages ``0..pos //
+    page_size`` while that is below ``p1``; after (a stop or a refused
+    allocation) it reads the ``ceil(p1 / page_size)`` pages it holds.  The
+    same walk as the kernel's ``need(p)``: ``p·PS <= pos`` and a present
+    table entry."""
+    pos = np.asarray(p0)[None, :] + np.arange(K)[:, None]
+    p1 = np.asarray(p1)[None, :]
+    pages = np.where(pos < p1, pos // page_size + 1, -(-p1 // page_size))
+    return int((pages * np.asarray(seated, bool)[None, :]).sum())
 
 
 def _probe_strategy_reason(cfg, rules=None) -> Optional[str]:
@@ -207,8 +270,9 @@ def fallback_report(cfg, rules=None) -> Dict[str, str]:
 
 def _kernel_interpret() -> bool:
     """Pallas kernels run compiled on TPU, interpreted elsewhere (CI's fake
-    CPU devices) — resolved at trace time, never a silent wrong-backend."""
-    return jax.default_backend() != "tpu"
+    CPU devices) — resolved at trace time from the same predicate as the
+    attention path, never a silent wrong-backend."""
+    return not KN.on_tpu()
 
 
 def _local_block_table(bt, chip_idx, npr: int):
@@ -648,22 +712,27 @@ def _cross_attn_decode(cfg, x, cp, ck, cv):
 # ---------------------------------------------------------------------------
 # serve_step factories.
 
+def _warn_kernel_fallbacks(cfg, rules):
+    """Never a silent fallback: a wanted fused kernel that the gate refuses,
+    and a probe strategy whose kernel surface degrades (the strategy itself
+    still runs on the jnp allocator), are logged and mirrored in
+    ``fallback_report``."""
+    if _fused_kernel_wanted(cfg) and not _fused_kernel_ok(cfg, rules):
+        logger.warning(
+            "fused decode kernel unavailable for %s — %s; "
+            "the jnp gather attends",
+            cfg.name, _fused_kernel_reason(cfg, rules))
+    if _probe_strategy_reason(cfg, rules) is not None:
+        logger.warning(
+            "probe strategy %s partially degraded for %s — %s",
+            cfg.probe_strategy, cfg.name, _probe_strategy_reason(cfg, rules))
+
+
 def make_serve_step(cfg, *, S_max: int, rules=None,
                     page_size: int = DEFAULT_PAGE_SIZE):
     """Returns serve_step(params, state, tokens [B,1], positions [B],
     [mrope_positions]) -> (logits [B,V], state')."""
-    if cfg.fused_kernel and not _fused_kernel_ok(cfg, rules):
-        # never a silent fallback: the caller asked for the fused kernel
-        logger.warning(
-            "fused decode kernel unavailable for %s — %s; "
-            "using the two-dispatch attend path",
-            cfg.name, _fused_kernel_reason(cfg, rules))
-    if _probe_strategy_reason(cfg, rules) is not None:
-        # the strategy itself still runs (jnp allocator); only the probe
-        # kernel surface degrades — logged, mirrored in fallback_report
-        logger.warning(
-            "probe strategy %s partially degraded for %s — %s",
-            cfg.probe_strategy, cfg.name, _probe_strategy_reason(cfg, rules))
+    _warn_kernel_fallbacks(cfg, rules)
     if rules is not None and _manual_decode_ok(cfg, rules):
         return _make_manual_serve_step(cfg, S_max=S_max, rules=rules,
                                        page_size=page_size)
@@ -716,15 +785,7 @@ def make_serve_megastep(cfg, *, S_max: int, K: int, rules=None,
     gspmd step.  The factory tags the returned fn with ``.megastep``
     (``"scan-K{K}"``) — recorded by dry-run artifacts so a silent fallback
     to per-token dispatch fails CI's ``--expect-fused``."""
-    if cfg.fused_kernel and not _fused_kernel_ok(cfg, rules):
-        logger.warning(
-            "fused decode kernel unavailable for %s — %s; "
-            "using the two-dispatch attend path",
-            cfg.name, _fused_kernel_reason(cfg, rules))
-    if _probe_strategy_reason(cfg, rules) is not None:
-        logger.warning(
-            "probe strategy %s partially degraded for %s — %s",
-            cfg.probe_strategy, cfg.name, _probe_strategy_reason(cfg, rules))
+    _warn_kernel_fallbacks(cfg, rules)
     if rules is not None and _manual_decode_ok(cfg, rules):
         return _make_manual_serve_megastep(cfg, S_max=S_max, K=K,
                                            rules=rules, page_size=page_size)
@@ -854,9 +915,7 @@ def _manual_decode_parts(cfg, *, S_max: int, rules,
     same body in an in-region ``lax.scan``."""
     mesh = rules.mesh
     pd_axes = _pd_axes(rules)
-    n_pd = 1
-    for a in pd_axes:
-        n_pd *= mesh.shape[a]
+    n_pd = _n_pd(rules)
     tp = mesh.shape["model"]
     kv_rep = TP.decode_kv_rep(cfg, tp)
     ssm_tp = cfg.family == "hybrid" and TP.decode_ssm_tp(cfg, tp)

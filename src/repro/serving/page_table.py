@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import kernels as KN
 from repro import obs as OBS
 from repro.core import batched as BT
 from repro.core import encoding as E
@@ -348,7 +349,7 @@ class PageTable:
         if use_kernel:
             from repro.kernels.probe import ops as PK
             found, slots = PK.probe_lookup(
-                table, keys, interpret=jax.default_backend() != "tpu",
+                table, keys, interpret=not KN.on_tpu(),
                 strategy=self.strategy)
         else:
             found, slots = BT.find_batch(table, keys,

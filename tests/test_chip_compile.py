@@ -14,6 +14,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 
+from repro import kernels as KN
+from repro import obs as OBS
 from repro.configs import get_config
 from repro.dist.sharding import serve_manual_rules
 from repro.kernels.fused_decode.fused import fused_decode_kernel
@@ -140,6 +142,25 @@ def test_fused_megastep_compiles_kernel(one_chip, monkeypatch):
     params, state = _one_chip_shapes(cfg, one_chip)
     compiled, _, total = _megastep_memory(cfg, params, state, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+    assert total <= HBM_BYTES, total / 2 ** 30
+
+
+def test_default_megastep_takes_kernel_on_tpu(one_chip, monkeypatch):
+    """On a TPU the default megastep, with no ``fused_kernel`` override,
+    attends through the compiled kernel, and the kernel's instruction sits
+    in the ``attend`` scope that ``attend_ms_per_megastep`` reads."""
+    monkeypatch.setattr(KN, "on_tpu", lambda: True)
+    cfg = _smoke_cfg(LAYERS)
+    assert cfg.fused_kernel is None
+    params, state = _one_chip_shapes(cfg, one_chip)
+    compiled, _, total = _megastep_memory(cfg, params, state, one_chip)
+    text = compiled.as_text()
+    kernels = [m.group(1) for m in map(OBS.timeplane._INSTR.match,
+                                       text.splitlines())
+               if m is not None and "tpu_custom_call" in m.group(2)]
+    assert kernels
+    scopes = OBS.scope_map(text)
+    assert {scopes.get(k) for k in kernels} == {"attend"}, kernels
     assert total <= HBM_BYTES, total / 2 ** 30
 
 

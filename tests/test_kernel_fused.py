@@ -232,6 +232,137 @@ def test_fused_gate_reasons_never_silent():
     assert "cross-attention" in EG._fused_kernel_reason(encdec, None)
 
 
+def test_fused_kernel_follows_the_platform(monkeypatch):
+    """Unset, ``fused_kernel`` follows the one "on TPU" predicate that also
+    picks the kernels' interpret mode: on a TPU the cells' qwen2.5-32b takes
+    the kernel and mamba2-2.7b gives the SSM reason; elsewhere the jnp
+    gather attends.  Forcing either way still works."""
+    from repro import kernels as KN
+    from repro.configs import get_config
+    from repro.launch.serve import serve_rules_for
+    qwen = dataclasses.replace(get_config("qwen2.5-32b"), num_layers=4,
+                               pad_heads_to=0)
+    mamba = get_config("mamba2-2.7b")
+    assert qwen.fused_kernel is None and mamba.fused_kernel is None
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    rules = serve_rules_for(qwen, mesh)
+
+    monkeypatch.setattr(KN, "on_tpu", lambda: True)
+    assert EG.fallback_report(qwen, rules)["fused_kernel"] == "ok"
+    assert EG.attention_path(qwen, rules) == "fused_decode_kernel"
+    assert not EG._kernel_interpret()
+    assert "SSM" in EG.fallback_report(mamba, rules)["fused_kernel"]
+    assert EG.attention_path(mamba, rules) is None
+    off = dataclasses.replace(qwen, fused_kernel=False)
+    assert EG.fallback_report(off, rules)["fused_kernel"] == \
+        "off (cfg.fused_kernel=False)"
+
+    monkeypatch.setattr(KN, "on_tpu", lambda: False)
+    reason = EG.fallback_report(qwen, rules)["fused_kernel"]
+    assert "jnp gather" in reason, reason
+    assert EG.attention_path(qwen, rules) == "jnp_gather"
+    assert EG._kernel_interpret()
+    on = dataclasses.replace(qwen, fused_kernel=True)
+    assert EG.fallback_report(on, rules)["fused_kernel"] == "ok"
+
+
+PS_CELL = 16
+
+
+def _cell_heads_cfg():
+    """qwen2.5-32b's attention (GQA 40/8, heads of 128) at a smoke width and
+    depth; the cells' 16-token pages are passed by the caller."""
+    return dataclasses.replace(get_smoke_config("qwen2.5-32b"),
+                               num_heads=40, num_kv_heads=8, head_dim=128,
+                               num_layers=2)
+
+
+def _lane_story(cfg, params, *, K, on_megastep=None, S_max=64):
+    """Drive one megastep program through the lane states the kernel must
+    get right, and return every megastep's tokens and a final step's
+    logits.  Lane 0 waits unseated (position 0, no pages), then is seated
+    at position 0; lane 1 stops at 14, then resumes across the page
+    boundary at 16 inside a megastep; lane 2 runs to 16 and is evicted (its
+    block-table rows -1); lane 3 stops at 9 and stays, inactive, holding
+    its pages.  ``on_megastep(p0, p1, seated, state)`` sees each one."""
+    B, PS = 4, PS_CELL
+    pt = PT.for_strategy("linear")
+    mega = jax.jit(EG.make_serve_megastep(cfg, S_max=S_max, K=K,
+                                          page_size=PS))
+    state, _ = EG.make_decode_state(cfg, B, S_max=S_max, page_size=PS)
+    tok = jax.random.randint(jax.random.PRNGKey(3), (B, 1), 0,
+                             cfg.vocab_size)
+    toks = []
+
+    def run(steps, active, stop, seated):
+        nonlocal state, tok
+        state["active"] = jnp.asarray(active)
+        for _ in range(steps // K):
+            p0 = np.asarray(state["pos"])
+            out, state = mega(params, state, tok,
+                              jnp.asarray(stop, jnp.int32))
+            tok = out[:, -1:]
+            toks.append(np.asarray(out))
+            if on_megastep is not None:
+                on_megastep(p0, np.asarray(state["pos"]),
+                            np.asarray(seated), state)
+
+    run(16, [False, True, True, True], [S_max, 14, S_max, 9],
+        [False, True, True, True])
+    assert list(np.asarray(state["pos"])) == [0, 14, 16, 9]
+    evict = jnp.asarray([False, False, True, False])
+    state["table"] = pt.free_sequences(
+        state["table"], state["seq_ids"], state["pos"], page_size=PS,
+        max_pages=S_max // PS, active=evict)
+    state["block_table"] = pt.invalidate_block_rows(state["block_table"],
+                                                    evict)
+    run(8, [True, True, False, False], [S_max, S_max, S_max, 9],
+        [True, True, False, True])
+    assert list(np.asarray(state["pos"])) == [8, 22, 16, 9]
+    step = jax.jit(EG.make_serve_step(cfg, S_max=S_max, page_size=PS))
+    logits, _ = step(params, state, tok, state["pos"])
+    return np.concatenate(toks, axis=1), np.asarray(logits)
+
+
+def test_engine_fused_matches_jnp_at_cell_heads():
+    """The engine's fused path against the jnp gather at the cells' head
+    shapes and pages, through a megastep: a lane at position 0, one that
+    crosses a page boundary inside the megastep, an evicted lane with -1
+    rows and an inactive lane holding pages."""
+    cfg = _cell_heads_cfg()
+    params, _ = get_model(cfg).init(cfg, jax.random.PRNGKey(0))
+    toks_k, lg_k = _lane_story(dataclasses.replace(cfg, fused_kernel=True),
+                               params, K=4)
+    toks_j, lg_j = _lane_story(dataclasses.replace(cfg, fused_kernel=False),
+                               params, K=4)
+    np.testing.assert_array_equal(toks_k, toks_j)
+    np.testing.assert_allclose(lg_k, lg_j, atol=1e-4, rtol=1e-5)
+
+
+def test_live_pages_read_counts_the_kernels_walk():
+    """``engine.live_pages_read`` over four-token megasteps equals the
+    kernel's own page walk (``p·PS <= pos`` and a present block-table
+    entry, after the step's allocation) counted one token at a time."""
+    cfg = dataclasses.replace(_cell_heads_cfg(), fused_kernel=False)
+    params, _ = get_model(cfg).init(cfg, jax.random.PRNGKey(0))
+    walked, rounds = [], []
+
+    def tally(p0, p1, seated, state):
+        bt = np.asarray(state["block_table"])
+        need = ((np.arange(bt.shape[1])[None, :] * PS_CELL <= p0[:, None])
+                & (bt >= 0))
+        walked.append(int(need.sum()))
+        rounds.append((p0, p1, seated))
+
+    _lane_story(cfg, params, K=1, on_megastep=tally)
+    assert len(walked) == 24
+    for r in range(0, 24, 4):
+        p0, _, seated = rounds[r]
+        p1 = rounds[r + 3][1]
+        assert EG.live_pages_read(p0, p1, seated, 4, PS_CELL) == \
+            sum(walked[r:r + 4]), r
+
+
 # ---------------------------------------------------------------------------
 # Satellite: adversarial probe-run fallback through the rebuild path.
 
