@@ -8,27 +8,25 @@ As a one-process tool for the chip,
     python3 -m chipbench.attribution --workload <cell> --seed <n> --seconds <s>
 
 runs one traced window of a cell as a ``--trace 1`` run does, and prints one
-JSON object: each program span's time per round, the device's idle time
-split by the innermost span the host was in, the longest idle gaps, the
-compiles in the window, and the megastep's device time per execution split
-by scope, with its unscoped share and the unscoped operations that lead it.
+JSON object: each program span's time per round; what the readers
+``host_critical_ms_per_round``, ``free_ms_per_round`` and
+``compiles_per_round`` read; the device's idle time split by the span that
+names each instant (``reduce.idle_by_span``); the longest idle gaps; the
+megastep's device time per execution split by scope, with its unscoped
+share and the unscoped operations that lead it; and the batcher's attention
+page reads (``ContinuousBatcher.attention_reads``).
 """
 from __future__ import annotations
 
 import argparse
 import bisect
-import glob
 import json
-import os
-import shutil
-import tempfile
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
-from chipbench import reduce
+from chipbench import reduce, spec
 from chipbench.reduce import Event, Interval, Trace
 
-PROGRAM_SPAN = "repro."
 PROGRAM = "megastep"
 
 
@@ -84,10 +82,11 @@ def scope_ms_per_execution(ctx, scope: str,
                            program: str = PROGRAM) -> Optional[float]:
     """Device ms per execution of ``program`` in the operations of one named
     scope; None where the trace has no device operations, the program
-    publishes no scope map or has no such scope, or never ran."""
+    published no scope map (``ctx.scopes``) or has no such scope, or never
+    ran."""
     if not ctx.trace.ops:
         return None
-    scopes = program_scopes(program)
+    scopes = ctx.scopes
     if not scopes or scope not in scopes.values():
         return None
     secs, execs = scoped_s(ctx.trace, ctx.win, program, scopes)
@@ -98,58 +97,9 @@ def scope_ms_per_execution(ctx, scope: str,
 
 # -- the tool ---------------------------------------------------------------
 
-def load(path: str, device_prefix: str = "/device:TPU:") -> Trace:
-    """``reduce.load``, keeping the program's spans beside the harness's."""
-    from jax.profiler import ProfileData
-    tr = reduce.load(path, device_prefix)
-    found = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
-                      recursive=True)
-    for plane in ProfileData.from_file(found[0]).planes:
-        if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                tr.spans.extend(e for e in reduce._events(line)
-                                if e.name.startswith(PROGRAM_SPAN))
-    return tr
-
-
-def idle_gaps(trace: Trace, win: Interval) -> List[Interval]:
-    """The intervals of the window with no operation on the first device."""
-    dev = sorted(trace.ops)[0]
-    busy = reduce.union(reduce._clip(trace.ops[dev], win))
-    edges = [win[0]] + [x for iv in busy for x in iv] + [win[1]]
-    return [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
-            if edges[i + 1] > edges[i]]
-
-
-def idle_by_span(gaps: List[Interval], spans: List[Event]
-                 ) -> Dict[str, float]:
-    """Idle seconds by the innermost span open at each instant of them
-    (``host`` where none is), by one sweep over every span's edges."""
-    marks = []
-    for k, s in enumerate(spans):
-        if s.name != reduce.WINDOW_SPAN:
-            marks += [(s.start, 1, k), (s.end, 0, k)]
-    for a, b in gaps:
-        marks += [(a, 3, -1), (b, 2, -1)]
-    marks.sort()
-    out: Dict[str, float] = defaultdict(float)
-    open_spans: Dict[int, Event] = {}
-    idle = 0
-    t0 = None
-    for t, kind, k in marks:
-        if idle and t0 is not None and t > t0:
-            inner = (min(open_spans.values(),
-                         key=lambda s: s.end - s.start).name
-                     if open_spans else "host")
-            out[inner] += (t - t0) / 1e9
-        t0 = t
-        if kind == 1:
-            open_spans[k] = spans[k]
-        elif kind == 0:
-            open_spans.pop(k, None)
-        else:
-            idle += 1 if kind == 3 else -1
-    return dict(out)
+# the per-layer readers whose numbers the report gives beside its own
+READERS = ("host_critical_ms_per_round", "free_ms_per_round",
+           "compiles_per_round")
 
 
 def span_ms_per_round(spans: List[Event], win: Interval, rounds: int
@@ -161,27 +111,23 @@ def span_ms_per_round(spans: List[Event], win: Interval, rounds: int
     return {k: v / 1e6 / rounds for k, v in sorted(acc.items())}
 
 
-def report(tr: Trace, rounds: int, compiles: int) -> dict:
-    win = reduce.window(tr)
-    spans = [s for s in tr.spans if s.name.startswith(PROGRAM_SPAN)]
-    per_round = span_ms_per_round(spans, win, rounds)
-    out = {"rounds": rounds, "window_s": (win[1] - win[0]) / 1e9,
-           "compiles_per_round": compiles / rounds,
-           "span_ms_per_round": per_round,
-           "host_critical_ms_per_round":
-               per_round.get("repro.serve.round", 0.0)
-               - per_round.get("repro.serve.wait", 0.0),
-           "free_ms_per_round": per_round.get("repro.serve.free", 0.0)}
+def report(ctx) -> dict:
+    """What a traced window's ``ctx`` (``harness.reader_context``) says of
+    where its time went."""
+    tr, win = ctx.trace, ctx.win
+    out = {"rounds": ctx.rounds, "window_s": (win[1] - win[0]) / 1e9,
+           "span_ms_per_round": span_ms_per_round(tr.program_spans, win,
+                                                  ctx.rounds)}
+    out.update((m, spec.metric_reader(m).read(ctx)) for m in READERS)
     if not tr.ops:
         return out
-    gaps = idle_gaps(tr, win)
+    gaps = reduce.idle_intervals(tr, win)
     out["idle_s"] = sum(b - a for a, b in gaps) / 1e9
     out["idle_s_by_span"] = dict(sorted(
-        idle_by_span(gaps, tr.spans).items(), key=lambda kv: -kv[1]))
-    out["longest_idle_gaps"] = [
-        [reduce._innermost(spans, (a + b) // 2) or "host", (b - a) / 1e9]
-        for a, b in sorted(gaps, key=lambda g: g[0] - g[1])[:10]]
-    scopes = program_scopes() or {}
+        reduce.idle_by_span(gaps, tr.program_spans + tr.spans).items(),
+        key=lambda kv: -kv[1]))
+    out["longest_idle_gaps"] = [list(g) for g in reduce.idle_gaps(tr, win)]
+    scopes = ctx.scopes or {}
     secs, execs = scoped_s(tr, win, PROGRAM, scopes)
     if execs:
         total, _ = reduce.program_time(tr, win, PROGRAM)
@@ -216,27 +162,18 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import numpy as np
-    from chipbench import harness, spec
+    from chipbench import harness
     s = harness.setup(spec.ROOT, args.workload)
-    from repro.obs import timeplane
     params = harness.draw_weights(s, args.seed)
-    server = harness.Server(s.cfg, params, s.conf, s.rules, args.seed)
+    server = harness.make_server(s, params, args.seed)
     server.warm_up(np.random.default_rng([args.seed, 2]))
-    stats = timeplane.listen_compiles()
-    marks = {}
-    trace_dir = tempfile.mkdtemp(prefix="chipbench-attribution-")
-    try:
-        rep = harness.drive(
-            server, s.mix, args.seed, args.seconds, trace_dir,
-            on_window_start=lambda: marks.setdefault(
-                "start", stats["compiles"]),
-            on_window_end=lambda: marks.setdefault("end", stats["compiles"]),
-            drain_cap=0.0)
-        tr = load(trace_dir)
-    finally:
-        shutil.rmtree(trace_dir, ignore_errors=True)
+    rep, tr, counts = harness.traced_drive(server, s.mix, args.seed,
+                                           args.seconds, drain_cap=0.0)
+    ctx = harness.reader_context(server, tr, rep, counts, s.chips,
+                                 spec.peaks(s.device["kind"]))
     out = {"workload": args.workload, "seed": args.seed}
-    out.update(report(tr, rep["rounds"], marks["end"] - marks["start"]))
+    out.update(report(ctx))
+    out["attention_reads"] = server.srv.attention_reads()
     print(json.dumps(out), flush=True)
     return 0
 

@@ -37,8 +37,7 @@ def main(argv=None, root=None) -> int:
     from chipbench import check, harness, spec
     root = spec.ROOT if root is None else root
     s = harness.setup(root, args.workload)
-    ref = spec.reference(s.conf["family"], root)
-    limits, model = s.conf["limits"], s.conf["model"]
+    ref, limits, model = s.ref, s.conf["limits"], s.conf["model"]
     rows = []
 
     def emit(row):
@@ -48,7 +47,7 @@ def main(argv=None, root=None) -> int:
     for i, seed in enumerate(int(x) for x in args.seeds.split(",")):
         t0 = time.perf_counter()
         params = harness.draw_weights(s, seed)
-        server = harness.Server(s.cfg, params, s.conf, s.rules, seed)
+        server = harness.make_server(s, params, seed)
         server.warm_up(np.random.default_rng([seed, 2]))
         rep = harness.drive(server, s.mix, seed, args.seconds)
         mism = server.block_table_mismatches()

@@ -4,8 +4,10 @@ comparison with the plain reference that decides ``correct``.
 
 The program is the system under test: this module takes from it the
 configuration, the sharding rules, the serving loop and its parameter
-layout, and nothing else.  Host spans and the client's delivery log are
-added by wrapping the batcher's bound methods from here.
+layout, and, for the per-layer readers, its own host spans, its metrics
+registry's counters and its megastep's scope map.  The harness's host spans
+and the client's delivery log are added by wrapping the batcher's bound
+methods from here.
 """
 from __future__ import annotations
 
@@ -21,7 +23,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from chipbench import check, e2e, flops, reduce, spec, traffic, weights
+from chipbench import attribution, check, e2e, reduce, spec, traffic, weights
 
 # a request due in the window (open loop) or seated in it (backlog) that
 # still has no first token this long after the window closes has failed.
@@ -86,6 +88,7 @@ def setup(root, workload: str):
     bench = spec.load_benchmark(root)
     c = spec.cell(bench, workload)
     conf = spec.config(bench, c["config"], root)
+    ref = family_reference(conf["family"], root)
     mix = spec.traffic(c["traffic"], root)
     traffic.check_fits(mix, int(conf["serving"]["max_len"]))
     chips = int(c["chips"])
@@ -98,9 +101,24 @@ def setup(root, workload: str):
     cfg = model_config(conf)
     mesh = make_mesh((1, chips), ("data", "model"),
                      devices=jax.devices()[:chips])
-    return SimpleNamespace(bench=bench, cell=c, conf=conf, mix=mix,
+    return SimpleNamespace(bench=bench, cell=c, conf=conf, ref=ref, mix=mix,
                            chips=chips, device=device, cfg=cfg,
                            rules=serve_rules_for(cfg, mesh))
+
+
+def family_reference(family: str, root):
+    """``reference/<family>.py``, which has to give the family's plain model
+    (``logits``) and the work its lanes need (``lane_flops``).  A family
+    without the count is refused here, before any weights are drawn."""
+    path = f"{spec.PACKAGE}/reference/{family}.py"
+    try:
+        ref = spec.reference(family, root)
+    except FileNotFoundError:
+        ref = None
+    if not callable(getattr(ref, "lane_flops", None)):
+        raise SystemExit(f"chipbench: no FLOP count for family {family!r}: "
+                         f"add lane_flops(cfg, p0, p1) to {path}")
+    return ref
 
 
 def draw_weights(s, seed: int):
@@ -110,17 +128,24 @@ def draw_weights(s, seed: int):
                         s.conf["weight_draw"], s.rules)
 
 
+def make_server(s, params, seed: int) -> "Server":
+    """The cell's ``Server`` as ``setup`` describes it."""
+    return Server(s.cfg, params, s.conf, s.rules, seed, s.ref.lane_flops)
+
+
 class Server:
     """The program's ``ContinuousBatcher`` with the client around it: it
     submits the traffic, keeps each request's delivery log on the host
-    clock, writes the harness spans, and counts each round's work."""
+    clock, writes the harness spans, and counts each round's work with the
+    family's ``lane_flops``."""
 
-    def __init__(self, cfg, params, conf: dict, rules, seed: int):
+    def __init__(self, cfg, params, conf: dict, rules, seed: int,
+                 lane_flops):
         from repro.launch.serve import ContinuousBatcher
         from repro.serving import engine as EG
         from repro.serving.sched import Scheduler
         s = conf["serving"]
-        self.cfg, self.conf = cfg, conf
+        self.cfg, self.conf, self.lane_flops = cfg, conf, lane_flops
         # token ids come from the published vocabulary, never from the
         # padding rows of a padded embedding
         self.vocab = min(cfg.vocab_size,
@@ -170,7 +195,7 @@ class Server:
                 if n and rec is not None:
                     rec.deliveries.append((t, n))
             self.round_flops.append(sum(
-                flops.lane_flops(self.cfg, a, b) for a, b in zip(p0, p1)))
+                self.lane_flops(self.cfg, a, b) for a, b in zip(p0, p1)))
             return out
         srv._absorb = absorb_and_log
 
@@ -330,6 +355,52 @@ def drive(server: Server, mix: dict, seed: int, seconds: float,
             "unserved": server.unserved(owed), "window_flops": window_flops}
 
 
+def registry_counts(srv) -> Dict[str, float]:
+    """The program registry's cumulative counts: its counters and its
+    compile listener's ``jit_compiles`` and ``jit_compile_s``."""
+    snap = srv.metrics.snapshot()
+    counts = dict(snap["counters"])
+    counts.update((k, v) for k, v in snap["gauges"].items()
+                  if k.startswith("jit_"))
+    return counts
+
+
+def traced_drive(server: Server, mix: dict, seed: int, seconds: float,
+                 drain_cap: Optional[float] = None):
+    """``drive`` with the window traced.  Returns its report, the trace, and
+    the registry's counts differenced over the window."""
+    edges = {}
+
+    def at(edge):
+        return lambda: edges.setdefault(edge, registry_counts(server.srv))
+    trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
+    try:
+        rep = drive(server, mix, seed, seconds, trace_dir,
+                    on_window_start=at("start"), on_window_end=at("end"),
+                    drain_cap=drain_cap)
+        tr = reduce.load(trace_dir)
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    counts = {k: v - edges["start"].get(k, 0)
+              for k, v in edges["end"].items()}
+    return rep, tr, counts
+
+
+def reader_context(server: Server, tr, rep: dict, counts: dict, chips: int,
+                   peaks: dict) -> SimpleNamespace:
+    """What a per-layer reader reads: the trace and its window, the rounds
+    and operations of the window, the chips and their peaks, the program's
+    counts over the window with the page table's probe length after it,
+    the harness's host spans, and the megastep's scope map (built only
+    where the trace has device operations for it to name)."""
+    return SimpleNamespace(
+        trace=tr, win=reduce.window(tr), rounds=rep["rounds"],
+        window_flops=rep["window_flops"], chips=chips, peaks=peaks,
+        counters=dict(counts, probe_p99=server.probe_p99()),
+        host_spans=HOST_SPANS,
+        scopes=attribution.program_scopes() if tr.ops else None)
+
+
 def end_to_end(server: Server, seconds: float, names) -> dict:
     """The cell's end-to-end metrics; one with no sample to read (a run
     that served nothing) stays out of the line."""
@@ -375,43 +446,35 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool,
     from repro.launch import compile_cache as CC
     bench, conf, chips, device = s.bench, s.conf, s.chips, s.device
     params = draw_weights(s, seed)
-    server = Server(s.cfg, params, conf, s.rules, seed)
+    server = make_server(s, params, seed)
     server.warm_up(np.random.default_rng([seed, 2]))
     jax.block_until_ready(server.srv.state)
 
-    trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
-    try:
-        with CC.count_compiles() as compiles:
-            rep = drive(server, s.mix, seed, seconds, trace_dir)
-        setup_s = server.t0 - t_start
-        tr = reduce.load(trace_dir) if trace else None
-    finally:
-        if trace_dir is not None:
-            shutil.rmtree(trace_dir, ignore_errors=True)
+    with CC.count_compiles() as compiles:
+        if trace:
+            rep, tr, counts = traced_drive(server, s.mix, seed, seconds)
+        else:
+            rep = drive(server, s.mix, seed, seconds)
+    setup_s = server.t0 - t_start
     log(f"compiles in the window and drain: {compiles['compiles']} "
         f"({compiles['compile_s']:.3f} s)")
     device["memory_peak_bytes"] = peak_bytes()
     mism = server.block_table_mismatches()
-    probe = server.probe_p99()
 
     result = {"correct": False,
               "attempted": len(rep["owed"]),
               "failed": len(rep["unserved"])}
     if trace:
-        win = reduce.window(tr)
-        ctx = SimpleNamespace(
-            trace=tr, win=win, rounds=rep["rounds"],
-            window_flops=rep["window_flops"], chips=chips,
-            peaks=spec.peaks(device["kind"], root),
-            counters={"probe_p99": probe}, host_spans=HOST_SPANS)
+        ctx = reader_context(server, tr, rep, counts, chips,
+                             spec.peaks(device["kind"], root))
         result["metrics"] = per_layer(
             root, spec.metrics_for(bench, workload, trace=True), ctx)
-        device["busy_s"] = reduce.device_busy_s(tr, win)
-        device["window_s"] = (win[1] - win[0]) / 1e9
+        device["busy_s"] = reduce.device_busy_s(tr, ctx.win)
+        device["window_s"] = (ctx.win[1] - ctx.win[0]) / 1e9
         result["breakdown"] = {
-            "device_ops": [list(x) for x in reduce.top_ops(tr, win)],
-            "idle_gaps": [list(x) for x in reduce.idle_gaps(tr, win)]}
-        del tr
+            "device_ops": [list(x) for x in reduce.top_ops(tr, ctx.win)],
+            "idle_gaps": [list(x) for x in reduce.idle_gaps(tr, ctx.win)]}
+        del tr, ctx
     else:
         result["metrics"] = end_to_end(
             server, seconds, spec.metrics_for(bench, workload, trace=False))
@@ -420,9 +483,8 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool,
 
     finished = server.finished()
     server.release()
-    ref = spec.reference(conf["family"], root)
     numbers = check.gap_numbers(check.token_gaps(
-        ref, params, conf["model"], finished,
+        s.ref, params, conf["model"], finished,
         np.random.default_rng([seed, 3])))
     checks = check.judge(conf["limits"], numbers,
                          unserved=len(rep["unserved"]),

@@ -1,13 +1,14 @@
 """From a ``jax.profiler`` trace to busy and idle time, per-program and
 collective device time, and the longest idle gaps, each named after the
-harness span the host was in.
+span the host was in.
 
 The trace is read with ``jax.profiler.ProfileData`` (JAX alone).  A device is
 a plane named ``/device:<platform>:<n>``; its ``XLA Ops`` line holds one
 event per executed operation and its ``XLA Modules`` line one per executed
 program (``jit_<name>(<id>)``).  The harness writes its own spans into the
-host planes with ``TraceAnnotation``; their names start with ``SPAN``.
-Every time is in nanoseconds on the trace's one clock.
+host planes with ``TraceAnnotation``; their names start with ``SPAN``.  The
+program writes its own (``repro/obs/timeplane.py``), named from
+``PROGRAM_SPAN``.  Every time is in nanoseconds on the trace's one clock.
 """
 from __future__ import annotations
 
@@ -19,6 +20,8 @@ from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
 SPAN = "chipbench."
+PROGRAM_SPAN = "repro."
+PROGRAM_ROUND_SPAN = PROGRAM_SPAN + "serve.round"
 WINDOW_SPAN = SPAN + "window"
 ROUND_SPAN = SPAN + "round"
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
@@ -37,10 +40,11 @@ class Event:
 @dataclasses.dataclass
 class Trace:
     """What the reduction needs of one trace: per device, its op and module
-    events; and the host's harness spans."""
+    events; the host's harness spans; and the program's own host spans."""
     ops: Dict[str, List[Event]]
     modules: Dict[str, List[Event]]
     spans: List[Event]
+    program_spans: List[Event] = dataclasses.field(default_factory=list)
 
 
 def _short(name: str) -> str:
@@ -66,7 +70,7 @@ def load(path: str, device_prefix: str = "/device:TPU:") -> Trace:
             raise FileNotFoundError(f"{len(found)} xplane files under {path}")
         path = found[0]
     data = ProfileData.from_file(path)
-    ops, modules, spans = {}, {}, []
+    ops, modules, spans, program_spans = {}, {}, [], []
     for plane in data.planes:
         if plane.name.startswith(device_prefix):
             for line in plane.lines:
@@ -76,9 +80,13 @@ def load(path: str, device_prefix: str = "/device:TPU:") -> Trace:
                     modules[plane.name] = _events(line)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                spans.extend(e for e in _events(line)
-                             if e.name.startswith(SPAN))
-    return Trace(ops=ops, modules=modules, spans=spans)
+                for e in _events(line):
+                    if e.name.startswith(SPAN):
+                        spans.append(e)
+                    elif e.name.startswith(PROGRAM_SPAN):
+                        program_spans.append(e)
+    return Trace(ops=ops, modules=modules, spans=spans,
+                 program_spans=program_spans)
 
 
 def window(trace: Trace) -> Interval:
@@ -178,36 +186,84 @@ def _innermost(spans: List[Event], t: int) -> Optional[str]:
               and s.name != WINDOW_SPAN]
     if not inside:
         return None
-    return min(inside, key=lambda s: s.end - s.start).name
+    return min(inside, key=_inner_first).name
+
+
+def _inner_first(s: Event) -> Tuple[bool, int]:
+    """The order in which open spans name an instant: the program's spans
+    before the harness's, the shortest (innermost) first."""
+    return not s.name.startswith(PROGRAM_SPAN), s.end - s.start
+
+
+def idle_intervals(trace: Trace, win: Interval) -> List[Interval]:
+    """The intervals of the window with no operation on the first device."""
+    dev = sorted(trace.ops)[0]
+    busy = union(_clip(trace.ops[dev], win))
+    edges = [win[0]] + [x for iv in busy for x in iv] + [win[1]]
+    return [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
+            if edges[i + 1] > edges[i]]
 
 
 def idle_gaps(trace: Trace, win: Interval, n: int = 10
               ) -> List[Tuple[str, float]]:
     """The ``n`` longest gaps with no operation on the first device, each
-    named after the innermost harness span open at the gap's middle
-    (``host`` where none is)."""
+    named after the innermost program span open at the gap's middle, else
+    the innermost harness span (``host`` where none is)."""
     if not trace.ops:
         return []
-    dev = sorted(trace.ops)[0]
-    busy = union(_clip(trace.ops[dev], win))
-    edges = [win[0]] + [x for iv in busy for x in iv] + [win[1]]
-    gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
-            if edges[i + 1] > edges[i]]
-    gaps.sort(key=lambda g: g[0] - g[1])
-    out = []
-    for a, b in gaps[:n]:
-        name = _innermost(trace.spans, (a + b) // 2) or "host"
-        out.append((name, (b - a) / 1e9))
-    return out
+    gaps = sorted(idle_intervals(trace, win), key=lambda g: g[0] - g[1])
+    spans = trace.program_spans + trace.spans
+    return [(_innermost(spans, (a + b) // 2) or "host", (b - a) / 1e9)
+            for a, b in gaps[:n]]
+
+
+def idle_by_span(gaps: List[Interval], spans: List[Event]
+                 ) -> Dict[str, float]:
+    """Idle seconds by the span that names each instant of them, as
+    ``idle_gaps`` names a gap (``host`` where none is open), by one sweep
+    over every span's edges."""
+    marks = []
+    for k, s in enumerate(spans):
+        if s.name != WINDOW_SPAN:
+            marks += [(s.start, 1, k), (s.end, 0, k)]
+    for a, b in gaps:
+        marks += [(a, 3, -1), (b, 2, -1)]
+    marks.sort()
+    out: Dict[str, float] = defaultdict(float)
+    open_spans: Dict[int, Event] = {}
+    idle = 0
+    t0 = None
+    for t, kind, k in marks:
+        if idle and t0 is not None and t > t0:
+            inner = (min(open_spans.values(), key=_inner_first).name
+                     if open_spans else "host")
+            out[inner] += (t - t0) / 1e9
+        t0 = t
+        if kind == 1:
+            open_spans[k] = spans[k]
+        elif kind == 0:
+            open_spans.pop(k, None)
+        else:
+            idle += 1 if kind == 3 else -1
+    return dict(out)
 
 
 def span_s(trace: Trace, win: Interval, names) -> float:
-    """Seconds of harness spans named in ``names`` that start in the
-    window."""
-    return sum(s.end - s.start for s in trace.spans
+    """Seconds of host spans, the harness's or the program's, named in
+    ``names`` that start in the window."""
+    return sum(s.end - s.start for s in trace.spans + trace.program_spans
                if s.name in names and win[0] <= s.start < win[1]) / 1e9
 
 
 def span_count(trace: Trace, win: Interval, name: str) -> int:
-    return sum(1 for s in trace.spans
+    return sum(1 for s in trace.spans + trace.program_spans
                if s.name == name and win[0] <= s.start < win[1])
+
+
+def program_ms_per_round(trace: Trace, win: Interval, name: str
+                         ) -> Optional[float]:
+    """ms of the program's spans ``name`` per serving round, over the
+    rounds whose ``PROGRAM_ROUND_SPAN`` starts in the window; None where
+    there is none (a program that writes no spans)."""
+    n = span_count(trace, win, PROGRAM_ROUND_SPAN)
+    return 1e3 * span_s(trace, win, (name,)) / n if n else None

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import flops
+
 _F8_MAX = 448.0                         # largest finite float8_e4m3fn
 _BUCKET = 512                           # sequences padded to a multiple
 
@@ -119,3 +121,11 @@ def logits(weights, model: dict, tokens, *, quant: bool = False):
         x = _layer(x, weights["layers"], i, m=m, quant=quant)
     return _head(x, weights["final_norm"]["scale"], emb,
                  eps=model["norm_epsilon"], quant=quant)
+
+
+def lane_flops(cfg, p0: int, p1: int) -> float:
+    """The operations one lane's tokens at positions ``p0`` to ``p1 - 1``
+    need, ``cfg`` being the configuration as run (``flops.ssm_lane_flops``):
+    a Mamba2 decoder's projections, O(1) recurrence and read-out, the
+    same at every position."""
+    return flops.ssm_lane_flops(cfg, p0, p1)

@@ -30,8 +30,7 @@ def main(argv=None) -> int:
     s = harness.setup(spec.ROOT, args.workload)
     params = harness.draw_weights(s, args.seed)
     for rate in (float(r) for r in args.rates.split(",")):
-        server = harness.Server(s.cfg, params, s.conf, s.rules,
-                                args.seed)
+        server = harness.make_server(s, params, args.seed)
         server.warm_up(np.random.default_rng([args.seed, 2]))
         queue = {}
 

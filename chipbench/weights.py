@@ -7,9 +7,18 @@ plain reference and the program read the same numbers and the reference
 takes nothing the program made.  Each leaf is drawn by its name:
 
 - matrices: truncated normal (two sigma) with std 1/sqrt(fan-in); the
-  embedding with the configuration's ``embedding_std``;
-- norm scales: 1 + 0.1 N(0, 1); biases: ``bias_std`` N(0, 1), so the QKV
-  bias path is exercised (zero biases would hide it);
+  embedding with the configuration's ``embedding_std``.  The fan-in comes
+  from the leaf's logical axes (``model_init``'s second tree), with the
+  axes that only stack copies (``layer``, ``experts``) left out: a leaf
+  whose last axis is ``embed`` writes back into the model width and reads
+  every axis before it (attention's out-projection reads heads x head
+  size); any other reads its first axis;
+- norm scales: 1 + 0.1 N(0, 1); biases (one axis beside the stacking
+  ones): ``bias_std`` N(0, 1), so a bias path is exercised (zero biases
+  would hide it).  Attention's per-head biases (``bq``, ``bk``, ``bv``:
+  heads x head size) have two axes and are drawn as matrices over their
+  first, std 1/sqrt(heads), as the benchmark has drawn them since its
+  limits were set;
 - Mamba2's ``A_log``, ``dt_bias`` and ``D`` as the Mamba2 paper initialises
   them: A in [1, 16], dt in [1e-3, 1e-1] log-uniform, D in [0.5, 1.5].
 """
@@ -21,15 +30,27 @@ import jax
 import jax.numpy as jnp
 
 
-def _names(path) -> list:
-    return [str(getattr(k, "key", getattr(k, "idx", k))) for k in path]
+def _key(k) -> str:
+    return str(getattr(k, "key", getattr(k, "idx", k)))
 
 
-def _draw_leaf(names, leaf, key, draw: dict):
+# axes that hold stacked copies of a matrix, not inputs or outputs of it
+STACKING = ("layer", "experts")
+
+
+def fan_in(axes, shape) -> int:
+    """The inputs a matrix leaf's matmul sums over, from its logical
+    ``axes``: every axis before a trailing ``embed``, else the first, the
+    stacking axes left out."""
+    sizes = [n for a, n in zip(axes, shape) if a not in STACKING]
+    axes = [a for a in axes if a not in STACKING]
+    reads = sizes[:-1] if axes[-1] == "embed" else sizes[:1]
+    return math.prod(reads)
+
+
+def _draw_leaf(name, axes, leaf, key, draw: dict):
     shape, dtype = leaf.shape, leaf.dtype
-    name = names[-1]
-    # leaves stacked over layers carry the layer count as their first dim
-    inner = shape[1:] if "layers" in names else shape
+    inner = [a for a in axes if a not in STACKING]
     u = lambda lo, hi: jax.random.uniform(key, shape, jnp.float32, lo, hi)
     if name in ("scale", "norm"):
         v = 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
@@ -49,13 +70,17 @@ def _draw_leaf(names, leaf, key, draw: dict):
         elif name.startswith("conv"):
             std = 0.5                                 # depthwise, width 4
         else:
-            # attention out [H, hd, d] reads H·hd inputs; others [in, ...]
-            fan_in = (inner[0] * inner[1]
-                      if name == "wo" and len(inner) == 3 else inner[0])
-            std = 1.0 / math.sqrt(fan_in)
+            std = 1.0 / math.sqrt(fan_in(axes, shape))
         v = std * jax.random.truncated_normal(key, -2.0, 2.0, shape,
                                               jnp.float32)
     return v.astype(dtype)
+
+
+def leaf_axes(axes_tree, path) -> tuple:
+    """The logical axes ``model_init`` gives the leaf at ``path``."""
+    for k in path:
+        axes_tree = axes_tree[getattr(k, "key", getattr(k, "idx", None))]
+    return tuple(axes_tree)
 
 
 def draw(model_init, cfg, seed: int, draw_spec: dict, rules=None):
@@ -73,8 +98,8 @@ def draw(model_init, cfg, seed: int, draw_spec: dict, rules=None):
                  else rules.tree_shardings(box["axes"], abstract))
 
     def make(key):
-        leaves = [_draw_leaf(_names(path), leaf, jax.random.fold_in(key, i),
-                             draw_spec)
+        leaves = [_draw_leaf(_key(path[-1]), leaf_axes(box["axes"], path),
+                             leaf, jax.random.fold_in(key, i), draw_spec)
                   for i, (path, leaf) in enumerate(flat)]
         return jax.tree_util.tree_unflatten(treedef, leaves)
 

@@ -1,6 +1,7 @@
 """Small cells for the benchmark's CPU tests: tiny models of both
-families, tiny traffic, and a checkout-shaped temporary directory that
-holds them (``write_smoke_root``)."""
+families, tiny traffic, a checkout-shaped temporary directory that holds
+them (``write_smoke_root``), and a trace recorded on the CPU in the form
+the chip's takes (``record_cpu_trace``)."""
 import json
 import pathlib
 import shutil
@@ -98,3 +99,48 @@ def write_smoke_root(root: pathlib.Path) -> pathlib.Path:
             m["workloads"] = [w for w, _, _ in SMOKE_CELLS + [TP_CELL]]
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root
+
+
+def record_cpu_trace(d: str, megastep, body):
+    """Trace ``body()`` into ``d`` with ``jax.profiler``; returns the trace
+    as ``reduce.load`` reads it and the same trace with the CPU's device
+    work in it.  The CPU has no device plane, so the CPU client thread's XLA
+    operations stand for the device's and the executions of the jitted
+    ``megastep`` (its function's name must be ``megastep``) for its program
+    events; everything else is what the chip's trace goes through."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from chipbench import reduce
+    from chipbench.reduce import Event, Trace
+    jax.profiler.start_trace(d)
+    body()
+    jax.profiler.stop_trace()
+    loaded = reduce.load(d)
+    data = ProfileData.from_file(glob.glob(
+        os.path.join(d, "**", "*.xplane.pb"), recursive=True)[0])
+    ops, mods = [], []
+    skip = ("ThreadpoolListener", "SlinkyThreadPool", "ThunkExecutor",
+            "end: ")
+    for plane in data.planes:
+        for line in plane.lines:
+            for e in line.events:
+                ev = Event(e.name, int(e.start_ns),
+                           int(e.start_ns + e.duration_ns))
+                if line.name.startswith("tf_XLA") and not \
+                        e.name.startswith(skip):
+                    ops.append(ev)
+                elif e.name == f"PjitFunction({megastep.__name__})":
+                    mods.append(Event("jit_megastep(7)", ev.start, ev.end))
+    # a call can show as nested events: keep the outermost of each
+    mods.sort(key=lambda e: (e.start, -e.end))
+    mods = [e for i, e in enumerate(mods)
+            if not any(o.start <= e.start and e.end <= o.end
+                       for o in mods[:i])]
+    cpu = Trace(ops={"/device:CPU:0": ops},
+                modules={"/device:CPU:0": mods}, spans=loaded.spans,
+                program_spans=loaded.program_spans)
+    return loaded, cpu

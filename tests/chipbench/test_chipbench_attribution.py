@@ -1,6 +1,7 @@
 """Device time by the megastep's named scopes, idle time by the program's
-host spans (``chipbench.attribution``), and the three per-layer readers of
-scoped device time, on synthetic traces and one recorded on the CPU."""
+host spans (``chipbench.attribution``, ``reduce.idle_by_span``), and the
+three per-layer readers of scoped device time, on synthetic traces and one
+recorded on the CPU."""
 import time
 from types import SimpleNamespace
 
@@ -48,6 +49,13 @@ SCOPES = {"fusion.1": "attend", "fusion.2": "allocator",
           "fusion.3": "state_freeze", "while.2": "attend"}
 
 
+def _ctx(tr, win):
+    """The readers' context as the harness builds it: the trace, its
+    window, and the scope map the live program publishes."""
+    return SimpleNamespace(trace=tr, win=win,
+                           scopes=attribution.program_scopes())
+
+
 @pytest.fixture
 def published():
     prog = _Program(dict(SCOPES))
@@ -65,7 +73,7 @@ def test_scoped_time_keeps_to_the_program(published):
     # that ran after the second execution ended are not the megastep's
     assert secs == pytest.approx({"attend": 60e-9, "allocator": 40e-9,
                                   "state_freeze": 12e-9, None: 88e-9})
-    ctx = SimpleNamespace(trace=tr, win=win)
+    ctx = _ctx(tr, win)
     got = {m: spec.metric_reader(m).read(ctx) for m in READERS}
     assert got == pytest.approx({"attend_ms_per_megastep": 30e-6,
                                  "allocator_ms_per_megastep": 20e-6,
@@ -81,7 +89,7 @@ def test_readers_average_over_chips(published):
                modules={"/device:TPU:0": one.modules["/device:TPU:0"],
                         "/device:TPU:1": one.modules["/device:TPU:0"]},
                spans=one.spans)
-    ctx = SimpleNamespace(trace=tr, win=reduce.window(tr))
+    ctx = _ctx(tr, reduce.window(tr))
     assert spec.metric_reader("attend_ms_per_megastep").read(ctx) == \
         pytest.approx(45e-6)
 
@@ -89,14 +97,15 @@ def test_readers_average_over_chips(published):
 def test_readers_find_nothing_to_read(published):
     tr = _two_programs()
     win = reduce.window(tr)
-    empty = SimpleNamespace(trace=Trace({}, {}, tr.spans), win=win)
+    empty = _ctx(Trace({}, {}, tr.spans), win)
     assert all(spec.metric_reader(m).read(empty) is None for m in READERS)
     # a program with no such scope (a model with no allocator)
     published.scopes = {"fusion.1": "attend"}
-    ctx = SimpleNamespace(trace=tr, win=win)
+    ctx = _ctx(tr, win)
     assert spec.metric_reader("allocator_ms_per_megastep").read(ctx) is None
     # no live program has published a map: the parent commit's program
     OBS.publish_scopes("megastep", _Program({}).megastep_scopes)
+    ctx = _ctx(tr, win)
     assert all(spec.metric_reader(m).read(ctx) is None for m in READERS)
 
 
@@ -107,7 +116,7 @@ def test_idle_split_by_innermost_span():
              Event("repro.serve.apply_plan", 30, 50),
              Event("repro.serve.free", 32, 45)]
     gaps = [(20, 40), (55, 70)]
-    got = attribution.idle_by_span(gaps, spans)
+    got = reduce.idle_by_span(gaps, spans)
     assert got == pytest.approx({
         "repro.serve.round": (10 + 3) / 1e9,
         "repro.serve.apply_plan": 2 / 1e9,
@@ -129,15 +138,17 @@ def test_load_keeps_program_spans_beside_the_harness(tmp_path):
                     time.sleep(0.01)
                 f(x).block_until_ready()
     jax.profiler.stop_trace()
-    harness_only = reduce.load(d)
-    both = attribution.load(d)
-    assert sorted(s.name for s in harness_only.spans) == \
+    tr = reduce.load(d)
+    # the harness's spans stay as they were; the program's are beside them
+    assert sorted(s.name for s in tr.spans) == \
         [reduce.ROUND_SPAN, reduce.WINDOW_SPAN]
-    names = sorted(s.name for s in both.spans)
-    assert names == sorted([reduce.ROUND_SPAN, reduce.WINDOW_SPAN,
-                            "repro.serve.round", "repro.serve.free"])
-    rep = attribution.report(both, rounds=1, compiles=0)
+    assert sorted(s.name for s in tr.program_spans) == \
+        ["repro.serve.free", "repro.serve.round"]
+    ctx = SimpleNamespace(trace=tr, win=reduce.window(tr), rounds=1,
+                          counters={"jit_compiles": 0}, scopes=None)
+    rep = attribution.report(ctx)
     assert rep["span_ms_per_round"]["repro.serve.free"] >= 9.0
-    assert rep["free_ms_per_round"] == \
-        rep["span_ms_per_round"]["repro.serve.free"]
+    assert rep["free_ms_per_round"] == pytest.approx(
+        rep["span_ms_per_round"]["repro.serve.free"])
     assert rep["host_critical_ms_per_round"] > 0
+    assert rep["compiles_per_round"] == 0

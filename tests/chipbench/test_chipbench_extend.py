@@ -51,6 +51,8 @@ def test_every_named_file_of_the_benchmark_exists():
         conf = spec.config(bench, c["name"])
         assert (ROOT / "chipbench" / "reference" /
                 f"{conf['family']}.py").exists()
+        ref = spec.reference(conf["family"])
+        assert callable(ref.logits) and callable(ref.lane_flops)
     for cell in bench["workloads"]:
         spec.traffic(cell["traffic"])
     for m in bench["per_layer"]:

@@ -97,7 +97,8 @@ def test_control_reads_above_sound_runs(smoke_root, on_cpu, workload):
     for seed in (1, 2, 3):
         params = weights.draw(get_model(cfg).init, cfg, seed,
                               conf["weight_draw"])
-        server = harness.Server(cfg, params, conf, None, seed)
+        server = harness.Server(cfg, params, conf, None, seed,
+                                ref.lane_flops)
         server.warm_up(np.random.default_rng([seed, 2]))
         harness.drive(server, mix, seed, 2.0)
         prog, ctrl, n = check.control_gaps(

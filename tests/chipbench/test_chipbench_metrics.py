@@ -1,12 +1,15 @@
 """End-to-end metric arithmetic on synthetic delivery logs, and the
-megastep's FLOP count against ``launch/flops_model``."""
+megastep's FLOP count (each family's ``reference/<family>.py``) against
+``launch/flops_model``."""
 import dataclasses
 import math
 
 import pytest
 
-from chipbench import e2e, flops
+from chipbench import e2e, spec
 from chipbench.e2e import Record
+
+FAMILY = {"qwen2.5-32b": "qwen2", "mamba2-2.7b": "mamba2"}
 
 
 def test_percentile_is_nearest_rank():
@@ -75,7 +78,8 @@ def test_flops_match_the_flops_model_at_smoke_shape(arch):
     fb = FM.executed_flops(cfg, ShapeConfig("d", S, B, "decode"))
     want = (fb.attn_proj + fb.attn_score / FM.PAGE_CAPACITY_WASTE + fb.mlp
             + fb.ssm + fb.logits + fb.router)
-    got = B * flops.lane_flops(cfg, S - 1, S)
+    lane_flops = spec.reference(FAMILY[arch]).lane_flops
+    got = B * lane_flops(cfg, S - 1, S)
     assert got == pytest.approx(want, rel=1e-12)
 
 
@@ -83,13 +87,14 @@ def test_flops_count_real_context_and_unpadded_heads():
     from repro.configs import get_config
     cfg = dataclasses.replace(get_config("qwen2.5-32b"), num_layers=4)
     assert cfg.n_q == 48                  # padded for TP; not counted
-    one = flops.lane_flops(cfg, 0, 1)
+    lane_flops = spec.reference("qwen2").lane_flops
+    one = lane_flops(cfg, 0, 1)
     # a lane that moved 8 positions from 100 attends 101..108 keys
-    eight = flops.lane_flops(cfg, 100, 108)
+    eight = lane_flops(cfg, 100, 108)
     attn = 4 * 2 * 2 * 40 * 128
     assert eight == pytest.approx(
         8 * (one - attn * 1) + attn * sum(range(101, 109)))
-    assert flops.lane_flops(cfg, 5, 5) == 0.0
+    assert lane_flops(cfg, 5, 5) == 0.0
 
 
 def test_gap_numbers_and_the_limits_that_judge_them():

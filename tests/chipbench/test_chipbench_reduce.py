@@ -1,8 +1,6 @@
 """The trace reduction, on a trace this test records on the CPU with
-``jax.profiler``.  The CPU has no device plane, so the test takes the CPU
-client thread's XLA operations as the device's and the executions of the
-jitted ``megastep`` as its program events; everything else is the same
-code the chip's trace goes through."""
+``jax.profiler`` (``chipbench_smoke.record_cpu_trace``: the CPU client
+thread's XLA operations stand for the device's), and on synthetic ones."""
 import time
 
 import jax
@@ -11,56 +9,30 @@ import pytest
 
 from chipbench import reduce
 from chipbench.reduce import Event, Trace
+from chipbench_smoke import record_cpu_trace
 
 SLEEP_S = 0.05
 
 
 @pytest.fixture(scope="module")
 def recorded(tmp_path_factory):
-    d = str(tmp_path_factory.mktemp("trace"))
-
     def megastep(x):
         return jnp.tanh(x @ x).sum()
 
     f = jax.jit(megastep)
     x = jnp.ones((256, 256), jnp.float32)
     f(x).block_until_ready()
-    jax.profiler.start_trace(d)
-    with jax.profiler.TraceAnnotation(reduce.WINDOW_SPAN):
-        for _ in range(3):
-            with jax.profiler.TraceAnnotation(reduce.ROUND_SPAN):
-                f(x).block_until_ready()
-                with jax.profiler.TraceAnnotation(
-                        reduce.SPAN + "plan_round"):
-                    time.sleep(SLEEP_S)
-    jax.profiler.stop_trace()
-    loaded = reduce.load(d)
-    from jax.profiler import ProfileData
-    import glob
-    import os
-    data = ProfileData.from_file(glob.glob(
-        os.path.join(d, "**", "*.xplane.pb"), recursive=True)[0])
-    ops, mods = [], []
-    skip = ("ThreadpoolListener", "SlinkyThreadPool", "ThunkExecutor",
-            "end: ")
-    for plane in data.planes:
-        for line in plane.lines:
-            for e in line.events:
-                ev = Event(e.name, int(e.start_ns),
-                           int(e.start_ns + e.duration_ns))
-                if line.name.startswith("tf_XLA") and not \
-                        e.name.startswith(skip):
-                    ops.append(ev)
-                elif e.name == "PjitFunction(megastep)":
-                    mods.append(Event("jit_megastep(7)", ev.start, ev.end))
-    # a call can show as nested events: keep the outermost of each
-    mods.sort(key=lambda e: (e.start, -e.end))
-    mods = [e for i, e in enumerate(mods)
-            if not any(o.start <= e.start and e.end <= o.end
-                       for o in mods[:i])]
-    cpu = Trace(ops={"/device:CPU:0": ops},
-                modules={"/device:CPU:0": mods}, spans=loaded.spans)
-    return loaded, cpu
+
+    def body():
+        with jax.profiler.TraceAnnotation(reduce.WINDOW_SPAN):
+            for _ in range(3):
+                with jax.profiler.TraceAnnotation(reduce.ROUND_SPAN):
+                    f(x).block_until_ready()
+                    with jax.profiler.TraceAnnotation(
+                            reduce.SPAN + "plan_round"):
+                        time.sleep(SLEEP_S)
+    return record_cpu_trace(str(tmp_path_factory.mktemp("trace")),
+                            megastep, body)
 
 
 def test_load_finds_the_harness_spans(recorded):
@@ -126,3 +98,21 @@ def test_collectives_average_over_devices():
     assert reduce.collective_s(tr, win) == pytest.approx(7e-9)
     assert reduce.program_time(tr, win, "megastep") == (30e-9, 1.0)
     assert reduce.program_name("jit_megastep(1234)") == "megastep"
+
+
+def test_idle_gaps_are_named_by_the_program_span_first():
+    """A gap is named after the innermost program span open at its middle,
+    even inside a shorter harness span; where none is open, after the
+    innermost harness span; where neither is, ``host``."""
+    tr = Trace(ops={"/device:TPU:0": [Event("fusion.1", 0, 10),
+                                      Event("fusion.2", 38, 50),
+                                      Event("fusion.3", 80, 100)]},
+               modules={},
+               spans=[Event(reduce.WINDOW_SPAN, 0, 120),
+                      Event(reduce.ROUND_SPAN, 0, 100),
+                      Event(reduce.SPAN + "apply_plan", 15, 35)],
+               program_spans=[Event("repro.serve.round", 1, 60),
+                              Event("repro.serve.apply_plan", 12, 38)])
+    assert reduce.idle_gaps(tr, reduce.window(tr)) == [
+        (reduce.ROUND_SPAN, 30e-9), ("repro.serve.apply_plan", 28e-9),
+        ("host", 20e-9)]

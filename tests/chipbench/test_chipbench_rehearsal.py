@@ -39,9 +39,13 @@ def test_traced_run_reads_the_trace(smoke_root, on_cpu):
     res = _run(smoke_root, "qwen-smoke.open", seed=5, trace=True)
     m = res["metrics"]
     # the CPU has no device plane: the device readers find nothing and
-    # stay out of the line, the host spans and the counter are there
+    # stay out of the line, the host spans and the counters are there,
+    # the harness's and the program's
     assert m["host_ms_per_round"]["value"] > 0
     assert "probe_len_p99" in m
+    assert m["host_critical_ms_per_round"]["value"] > 0
+    assert m["free_ms_per_round"]["value"] >= 0
+    assert m["compiles_per_round"]["value"] >= 0
     assert "megastep_ms" not in m and "device_idle_share" not in m
     assert res["device"]["window_s"] > 0
     assert res["correct"] is True, res["checks"]
@@ -67,7 +71,7 @@ def test_lead_in_fills_the_lanes_before_the_window(smoke_root, on_cpu):
     from chipbench import e2e, harness, spec
     s = harness.setup(smoke_root, "qwen-smoke.open")
     params = harness.draw_weights(s, 8)
-    server = harness.Server(s.cfg, params, s.conf, s.rules, 8)
+    server = harness.make_server(s, params, 8)
     server.warm_up(np.random.default_rng([8, 2]))
     busy = {}
     rep = harness.drive(server, dict(s.mix, lead_in_s=1.0), 8, 1.0,
